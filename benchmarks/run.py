@@ -12,6 +12,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         breakdown,
         end_to_end,
@@ -58,10 +61,10 @@ def main() -> None:
         # availability under a seeded fault storm: rollback/quarantine
         # recovery must be bitwise-exact (BENCH_serving.json["fault_recovery"])
         "perf_fault_recovery": serving_load.run_fault_recovery,
-        # device-scaling sweep; fork-safe (re-execs itself with fresh
-        # XLA_FLAGS), so the tracked sharded_scaling section can never go
-        # stale relative to the serving_load section written above
-        "perf_serving_sharded": serving_load.run_sharded_subprocess,
+        # device-scaling sweep: in-process over the real chips when an
+        # accelerator is present; a CPU host rehearses it in a child with
+        # forced host devices
+        "perf_serving_sharded": serving_load.run_sharded_sweep,
         "roofline": roofline.run,
     }
     only = os.environ.get("ONLY")

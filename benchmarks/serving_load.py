@@ -17,19 +17,19 @@ admission fills are small — is WELL below the full-batch number: expect
 high utilization even at the lowest load factor.  The saturation signal to
 read is queueing delay and throughput plateau, not utilization.
 
-The ``--sharded-worker`` half sweeps SERVING-MESH device counts at fixed
-batch size (the PR-4 lane-sharding backend): each admission batch's lanes
-are partitioned over a 1-D mesh, so a device only runs its own lane block's
-while-loop — stragglers stall 1/D of the batch instead of all of it, and
-the per-device programs execute concurrently.  The sweep needs
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE jax
-initializes, so the parent entrypoint re-execs itself into a worker
-subprocess pinned to CPU with that flag set — the sweep is always a
-host-device SIMULATION (a real TPU deployment hands
-``make_serving_mesh`` over its actual chips to ``BatchedFusedServer``
-instead of re-execing).  A tighter-than-default delta makes iteration
-counts heterogeneous across lanes — the regime where straggler
-localization pays.
+The sharded sweep (``run_sharded_sweep``) varies SERVING-MESH device
+counts at fixed batch size (the PR-4 lane-sharding backend): each admission
+batch's lanes are partitioned over a 1-D mesh, so a device only runs its
+own lane block's while-loop — stragglers stall 1/D of the batch instead of
+all of it, and the per-device programs execute concurrently.  With an
+accelerator present it runs in-process over the real devices (device
+counts capped at what is visible; the process that holds the chips is the
+only one that may use them).  On a CPU host it is a rehearsal: the
+``--sharded-worker`` child is re-exec'd with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before jax
+initializes.  A tighter-than-default delta makes iteration counts
+heterogeneous across lanes — the regime where straggler localization
+pays.
 Writes ``BENCH_serving.json`` at the repo root.
 """
 from __future__ import annotations
@@ -54,8 +54,8 @@ MAX_WAIT_MS = 20.0
 LOAD_FACTORS = (0.3, 1.0, 3.0)
 N_REQUESTS = 48
 
-# ---- sharded lane-parallel sweep (run in the forced-device subprocess) ----
-DEVICE_COUNTS = (1, 2, 4, 8)
+# ---- sharded lane-parallel sweep (one TPU v5e host holds 4 chips) ----
+DEVICE_COUNTS = (1, 2, 4)
 # fraction of the pipeline's default delta: tight enough that requests
 # iterate a heterogeneous number of times (the straggler regime)
 SHARDED_DELTA_FRAC = 0.35
@@ -365,12 +365,11 @@ def run_continuous(pipeline: str = PIPE) -> list[str]:
 # Device-scaling sweep: sharded lanes over a 1-D serving mesh
 # ------------------------------------------------------------------------
 def run_sharded(pipeline: str = PIPE) -> list[str]:
-    """Sweep serving-mesh sizes at fixed batch size (worker half).
+    """Sweep serving-mesh sizes at fixed batch size, in this process.
 
-    Must run in a process with >= max(DEVICE_COUNTS) visible devices — the
-    parent entrypoint (``run_sharded_subprocess``) forces them on CPU.  The
-    same saturating Poisson trace (rate pinned to 3x the 1-device capacity)
-    is replayed at every device count, so ``throughput_rps`` isolates the
+    Covers the ``DEVICE_COUNTS`` that fit the visible devices.  The same
+    saturating Poisson trace (rate pinned to 3x the 1-device capacity) is
+    replayed at every device count, so ``throughput_rps`` isolates the
     sharding effect: lane blocks run concurrently and each device's
     while-loop exits at ITS stragglers, not the batch's.
     """
@@ -379,13 +378,7 @@ def run_sharded(pipeline: str = PIPE) -> list[str]:
     from repro.launch.mesh import make_serving_mesh
 
     n_visible = len(jax.devices())
-    if n_visible < max(DEVICE_COUNTS):
-        raise RuntimeError(
-            f"need {max(DEVICE_COUNTS)} devices, have {n_visible}; run via "
-            "run_sharded_subprocess() or set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{max(DEVICE_COUNTS)}"
-        )
+    counts = [d for d in DEVICE_COUNTS if d <= n_visible]
     b = bundle(pipeline)
     cfg = BiathlonConfig(
         **DEFAULT_CFG, delta=b.pipeline.delta_default * SHARDED_DELTA_FRAC
@@ -400,10 +393,11 @@ def run_sharded(pipeline: str = PIPE) -> list[str]:
         "rate_factor": SHARDED_RATE_FACTOR,
         "capacity_method": "best_of_5",
         "config": {"m": cfg.m, "m_sobol": cfg.m_sobol, "tau": cfg.tau},
+        "platform": jax.devices()[0].platform,
         "devices": [],
     }
     rate = None
-    for d in DEVICE_COUNTS:
+    for d in counts:
         srv = BatchedFusedServer(
             b, cfg, batch_size=BATCH_SIZE, mesh=make_serving_mesh(d)
         )
@@ -440,11 +434,27 @@ def run_sharded(pipeline: str = PIPE) -> list[str]:
     return out
 
 
-def run_sharded_subprocess(pipeline: str = PIPE) -> list[str]:
-    """Re-exec this module as a worker with forced host devices.
+def run_sharded_sweep(pipeline: str = PIPE) -> list[str]:
+    """The device-scaling sweep over the devices this host has.
 
-    jax fixes its device list at first initialization, so the sweep cannot
-    run in a process that already touched jax with the default flags.
+    With an accelerator the sweep runs here, over the real chips: a child
+    process could not reach a chip this process already holds.  On a CPU
+    host it re-execs a worker with forced host devices
+    (:func:`run_sharded_subprocess`), the multi-device rehearsal.
+    """
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return run_sharded(pipeline)
+    return run_sharded_subprocess(pipeline)
+
+
+def run_sharded_subprocess(pipeline: str = PIPE) -> list[str]:
+    """Re-exec this module as a worker with forced host devices (CPU only).
+
+    jax fixes its device list at first initialization, so the CPU
+    rehearsal cannot run in a process that already touched jax with the
+    default flags.
     """
     from repro.launch.mesh import forced_host_devices_env
 
@@ -610,5 +620,5 @@ if __name__ == "__main__":
             print(row)
         for row in run_fault_recovery():
             print(row)
-        for row in run_sharded_subprocess():
+        for row in run_sharded_sweep():
             print(row)

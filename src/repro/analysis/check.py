@@ -237,17 +237,22 @@ def rollback_findings(
     findings: list[LintFinding] = []
     for l in lanes:
         same_z = bool(np.array_equal(want["z"][l], got["z"][l]))
-        same_y = bool(
-            np.asarray(want["y_hat"][l]).tobytes()
-            == np.asarray(got["y_hat"][l]).tobytes()
-        )
-        if not (same_z and same_y):
+        # the iteration counter and the guarantee probability are carry
+        # too: a request that converges on the next step reaches the same
+        # z and y_hat from a wrong counter, but not the same it / prob
+        same_it = bool(want["it"][l] == got["it"][l])
+        same_y = (np.asarray(want["y_hat"][l]).tobytes()
+                  == np.asarray(got["y_hat"][l]).tobytes())
+        same_p = (np.asarray(want["prob"][l]).tobytes()
+                  == np.asarray(got["prob"][l]).tobytes())
+        if not (same_z and same_it and same_y and same_p):
             findings.append(LintFinding(
                 contract="rollback_replay", executable=exe,
                 where=f"lane[{l}]",
                 message=(
                     "replay after chunk rollback diverged from the "
-                    f"fault-free oracle (z match={same_z}, y_hat "
+                    f"fault-free oracle (z match={same_z}, it match="
+                    f"{same_it}, prob match={same_p}, y_hat "
                     f"{got['y_hat'][l]:.6g} vs {want['y_hat'][l]:.6g}): "
                     "the checkpoint must restore every chunk-mutable "
                     "carry leaf"

@@ -37,11 +37,7 @@ from collections.abc import Callable, Iterator
 from typing import Any
 
 import jax
-
-try:  # jax >= 0.5 removes these from jax.core; jax.extend.core has both
-    from jax.extend import core as jax_core
-except ImportError:  # pragma: no cover - old jax
-    from jax import core as jax_core  # type: ignore[no-redef]
+from jax.extend import core as jax_core
 
 __all__ = [
     "LintFinding",
@@ -55,7 +51,8 @@ __all__ = [
 
 #: Primitives that are a device->host synchronization on every execution.
 HOST_CALLBACK_PRIMITIVES = frozenset(
-    {"pure_callback", "io_callback", "debug_callback", "callback"}
+    {"pure_callback", "io_callback", "debug_callback", "debug_print",
+     "callback"}
 )
 
 #: Loop primitives whose nested jaxprs execute once per iteration.

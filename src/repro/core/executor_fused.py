@@ -277,7 +277,7 @@ def shard_lanes_executor(lane_fn, mesh, *, axis: str = "lanes", donate_vals: boo
     the single-device megabatch.
 
     The leading dimension of every argument must be divisible by the mesh
-    size (callers pad to a fixed lane count anyway).  ``check_rep=False``
+    size (callers pad to a fixed lane count anyway).  ``check_vma=False``
     because the executor closes over large replicated constants and runs a
     ``while_loop`` — the conservative replication checker rejects that
     combination without adding safety for a collective-free program.
@@ -288,17 +288,16 @@ def shard_lanes_executor(lane_fn, mesh, *, axis: str = "lanes", donate_vals: boo
     input to that output and per-batch serving stops copying the buffer —
     the donation contract asserted via ``memory_analysis`` in tests.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     spec = PartitionSpec(axis)
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             jax.vmap(lane_fn),
             mesh=mesh,
             in_specs=(spec,) * 8,
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0,) if donate_vals else (),
     )
@@ -317,19 +316,21 @@ def shard_lanes_state_executor(chunk_fn, mesh, *, axis: str = "lanes",
     table (argument 0) is donated by default so XLA updates it in place
     across chunks instead of copying every leaf each dispatch.
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec
+    from jax.sharding import NamedSharding, PartitionSpec
 
     spec = PartitionSpec(axis)
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             jax.vmap(chunk_fn),
             mesh=mesh,
             in_specs=(spec,),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0,) if donate_state else (),
+        # pinned so zero-size leaves keep the lanes sharding they came in
+        # with (XLA reports them replicated), one cache key per bucket
+        out_shardings=NamedSharding(mesh, spec),
     )
 
 

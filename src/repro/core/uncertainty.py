@@ -20,6 +20,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.qmc import uniform_to_normal
 
@@ -119,5 +120,23 @@ def sample_features_fused(
         emp = jax.vmap(
             lambda col, i: col[i], in_axes=(0, 1), out_axes=1
         )(replicates, idx)                            # (m, h)
-        rows = rows.at[:, hol_idx].set(emp)
+        rows = set_columns(rows, hol_idx, emp)
     return rows
+
+
+def set_columns(x: jnp.ndarray, idx, cols: jnp.ndarray) -> jnp.ndarray:
+    """``x.at[..., idx].set(cols)`` for a concrete index list ``idx``.
+
+    Built from static column slices and one stack, so no scatter reaches
+    XLA: the TPU compiler aborts (a sublane-gather CHECK in its sorted
+    scatter emitter) when it fuses the replicate gather above into the
+    scatter that ``.at[].set`` lowers to.
+    """
+    pos = {int(j): p for p, j in enumerate(np.asarray(idx))}
+    return jnp.stack(
+        [
+            cols[..., pos[j]] if j in pos else x[..., j]
+            for j in range(x.shape[-1])
+        ],
+        axis=-1,
+    )

@@ -79,7 +79,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(B, H, Sq, Dv) attention output; KV heads must be pre-expanded."""
     b, h, sq, d = q.shape

@@ -39,6 +39,13 @@ __all__ = [
 AFC_REF_MAX_CAP = 1024
 
 
+def _on_tpu() -> bool:
+    """The one backend query behind kernel routing and interpret mode: on a
+    TPU the Pallas kernels compile with Mosaic, anywhere else a forced
+    kernel call runs in the Pallas interpreter."""
+    return jax.default_backend() == "tpu"
+
+
 def _resolve_backend(use_kernel: bool | None) -> bool:
     """None = auto: the REPRO_AFC_BACKEND env override (ref | kernel), else
     Pallas on TPU and the jnp oracle elsewhere.  CI runs the tier-1 suite
@@ -49,7 +56,7 @@ def _resolve_backend(use_kernel: bool | None) -> bool:
             return True
         if env == "ref":
             return False
-        return jax.default_backend() == "tpu"
+        return _on_tpu()
     return use_kernel
 
 
@@ -124,7 +131,7 @@ def prefix_power_sums(
     """
     if _resolve_backend(use_kernel):
         return prefix_power_sums_kernel(
-            vals, shift, interpret=jax.default_backend() != "tpu"
+            vals, shift, interpret=not _on_tpu()
         )
     return prefix_power_sums_ref(vals, shift)
 
@@ -143,7 +150,7 @@ def moments(
     """
     if _resolve_backend(use_kernel):
         return sampled_moments(
-            vals, z, shift, interpret=jax.default_backend() != "tpu"
+            vals, z, shift, interpret=not _on_tpu()
         )
     return sampled_moments_ref(vals, z, shift)
 
@@ -207,7 +214,7 @@ def masked_quantile_estimates(
     targets = bootstrap_rank_targets(z, qs, key, n_boot)
     if _resolve_backend(use_kernel):
         sel = masked_select_ranks(
-            vals, z, targets, interpret=jax.default_backend() != "tpu"
+            vals, z, targets, interpret=not _on_tpu()
         )
     else:
         sel = masked_select_ranks_ref(vals, z, targets)

@@ -94,7 +94,7 @@ def prefix_power_sums_ref(
 
 def _prefix_kernel(shift_ref, vals_ref, out_ref, hi_ref, lo_ref, *, block_c: int):
     ci = pl.program_id(1)
-    v = vals_ref[...].astype(jnp.float32) - shift_ref[...][:, None]
+    v = vals_ref[...].astype(jnp.float32) - shift_ref[...]
     p = _powers(v)                               # (block_k, block_c, 4)
 
     # within-tile inclusive scan: log-step doubling (Mosaic-safe static
@@ -115,7 +115,8 @@ def _prefix_kernel(shift_ref, vals_ref, out_ref, hi_ref, lo_ref, *, block_c: int
     carry_lo = lo_ref[...]
     # add the smaller correction first so it is not absorbed by the carry
     out_ref[...] = carry_hi[:, None, :] + (p + carry_lo[:, None, :])
-    hi, lo = kahan_step(carry_hi, carry_lo, p[:, -1, :])
+    last = jax.lax.slice_in_dim(p, block_c - 1, block_c, axis=1)[:, 0, :]
+    hi, lo = kahan_step(carry_hi, carry_lo, last)
     hi_ref[...] = hi
     lo_ref[...] = lo
 
@@ -126,8 +127,8 @@ def prefix_power_sums(
     shift: jnp.ndarray | None = None,  # (k,) f32 accumulation origin
     *,
     block_k: int = 8,
-    block_c: int = 1024,
-    interpret: bool = True,            # CPU container: interpret; TPU: False
+    block_c: int = 256,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Pallas twin of :func:`prefix_power_sums_ref`: (k, cap, 4) tables.
 
@@ -138,6 +139,11 @@ def prefix_power_sums(
     (k, cap).  The sliced-off padded region is NOT a valid prefix
     continuation (zero-padded columns accumulate ``(0 - shift)^p``, not 0);
     only the returned [:k, :cap] entries are meaningful.
+
+    ``block_c`` is 256 because the TPU pads the output block's minor dim of
+    4 to 128 lanes in VMEM: at 1,024 the kernel needs ~21 MB of the 16 MiB
+    scoped VMEM.  ``shift`` enters as a ``(k, 1)`` column so its block obeys
+    the (8, 128) tiling rule.
     """
     k, cap = vals.shape
     if shift is None:
@@ -154,7 +160,7 @@ def prefix_power_sums(
         functools.partial(_prefix_kernel, block_c=block_c),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, j: (i,)),
+            pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, block_c), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec(
@@ -166,7 +172,7 @@ def prefix_power_sums(
             pltpu.VMEM((block_k, N_POWERS), jnp.float32),
         ],
         interpret=interpret,
-    )(shift.astype(jnp.float32), vals)
+    )(shift.astype(jnp.float32)[:, None], vals)
     return out[:k, :cap]
 
 

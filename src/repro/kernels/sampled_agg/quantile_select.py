@@ -41,13 +41,13 @@ def _kernel(
 ):
     ci = pl.program_id(1)
     cj = pl.program_id(2)
-    z = z_ref[...]                                   # (block_k,)
+    z = z_ref[...]                                   # (block_k, 1)
     vi = vals_i_ref[...].astype(jnp.float32)         # (block_k, block_ci)
     vj = vals_j_ref[...].astype(jnp.float32)         # (block_k, block_cj)
     coli = ci * block_ci + jax.lax.broadcasted_iota(jnp.int32, vi.shape, 1)
     colj = cj * block_cj + jax.lax.broadcasted_iota(jnp.int32, vj.shape, 1)
-    vi = jnp.where(coli < z[:, None], vi, jnp.inf)
-    vj = jnp.where(colj < z[:, None], vj, jnp.inf)
+    vi = jnp.where(coli < z, vi, jnp.inf)
+    vj = jnp.where(colj < z, vj, jnp.inf)
 
     @pl.when(cj == 0)
     def _init_ranks():
@@ -83,10 +83,10 @@ def masked_select_ranks(
     z: jnp.ndarray,           # (k,) int32 live prefix lengths
     targets: jnp.ndarray,     # (k, R) int32 ranks into the sorted prefix
     *,
-    block_k: int = 4,
+    block_k: int = 8,
     block_ci: int = 128,
     block_cj: int = 128,
-    interpret: bool = True,   # CPU container: interpret; TPU: False
+    interpret: bool,
 ) -> jnp.ndarray:
     """(k, R) order statistics of each z-prefix at ``targets`` ranks.
 
@@ -96,6 +96,8 @@ def masked_select_ranks(
     the empty-prefix convention upstream).  Shapes need not divide the block
     sizes — inputs are padded (padded rows carry z = 0, padded targets point
     past the buffer and select nothing, contributing 0 to unsliced rows).
+    On the TPU a row block is a multiple of 8 or the whole array, and ``z``
+    enters as a ``(k, 1)`` column for the same tiling rule.
     """
     k, cap = vals.shape
     r = targets.shape[1]
@@ -126,7 +128,7 @@ def masked_select_ranks(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, ci, cj: (i,)),
+            pl.BlockSpec((block_k, 1), lambda i, ci, cj: (i, 0)),
             pl.BlockSpec((block_k, block_ci), lambda i, ci, cj: (i, ci)),
             pl.BlockSpec((block_k, block_cj), lambda i, ci, cj: (i, cj)),
             pl.BlockSpec((block_k, rp), lambda i, ci, cj: (i, 0)),
@@ -135,5 +137,5 @@ def masked_select_ranks(
         out_shape=jax.ShapeDtypeStruct((kp, rp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_k, block_ci), jnp.int32)],
         interpret=interpret,
-    )(z, vals, vals, targets)
+    )(z[:, None], vals, vals, targets)
     return out[:k, :r]

@@ -34,12 +34,12 @@ def _kernel(z_ref, shift_ref, vals_ref, out_ref, comp_ref, *, block_c: int, n_c:
     ci = pl.program_id(1)
     # (block_k, block_c) tile of sample values
     v = vals_ref[...].astype(jnp.float32)
-    z = z_ref[...]  # (block_k,) int32 live sample sizes
-    shift = shift_ref[...]  # (block_k,) f32 per-feature accumulation origin
+    z = z_ref[...]  # (block_k, 1) int32 live sample sizes
+    shift = shift_ref[...]  # (block_k, 1) f32 per-feature accumulation origin
     col0 = ci * block_c
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-    mask = (cols < z[:, None]).astype(jnp.float32)
-    v = (v - shift[:, None]) * mask
+    mask = (cols < z).astype(jnp.float32)
+    v = (v - shift) * mask
     v2 = v * v
     tile = jnp.stack(
         [
@@ -78,14 +78,16 @@ def sampled_moments(
     *,
     block_k: int = 8,
     block_c: int = 1024,
-    interpret: bool = True,       # CPU container: interpret; TPU: False
+    interpret: bool,
 ) -> jnp.ndarray:
     """(k, 5) power sums [count, s1, s2, s3, s4] of ``vals - shift`` over
     each valid prefix (see ref.py for the shift rationale; None = no shift).
 
     Shapes need not divide the block sizes: inputs are zero-padded up to the
     tile grid (padded rows carry z=0, so they contribute nothing) and the
-    output is sliced back to k rows.
+    output is sliced back to k rows.  ``z`` and ``shift`` enter as ``(k, 1)``
+    columns: Mosaic refuses 1-D blocks that are neither the whole array nor
+    a multiple of 128.
     """
     k, cap = vals.shape
     if shift is None:
@@ -103,13 +105,13 @@ def sampled_moments(
         functools.partial(_kernel, block_c=block_c, n_c=capp // block_c),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_k,), lambda i, j: (i,)),
-            pl.BlockSpec((block_k,), lambda i, j: (i,)),
+            pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((block_k, block_c), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((block_k, 5), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, 5), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_k, 5), jnp.float32)],
         interpret=interpret,
-    )(z, shift.astype(jnp.float32), vals)
+    )(z[:, None], shift.astype(jnp.float32)[:, None], vals)
     return out[:k]

@@ -41,7 +41,7 @@ def sobol_points(
     skip: int = 0,
     *,
     block_m: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(m, dim) uint32 Sobol points, bit-exact with the jnp/scipy oracle."""
     from repro.core.sobol_tables import DIRECTION_NUMBERS

@@ -70,7 +70,7 @@ def ensemble_sum(
     depth: int,
     block_m: int = 256,
     block_t: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """(m,) sum of per-tree leaf values (caller adds base / divides)."""
     t, m_nodes = feature.shape

@@ -73,8 +73,16 @@ def forced_host_devices_env(n_devices: int) -> dict:
     ignore it and defeat the simulation), and prepend this package's
     ``src`` root to ``PYTHONPATH`` so the child can ``import repro`` no
     matter its cwd.  Real multi-chip runs don't go through this: they pass
-    ``make_serving_mesh`` over the actual devices to the server directly.
+    ``make_serving_mesh`` over the actual devices to the server directly,
+    and on a host with an accelerator this raises rather than hand a child
+    the CPU in its place.
     """
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "forced host devices are a CPU rehearsal; this host has "
+            f"{jax.default_backend()!r} devices — pass make_serving_mesh "
+            "over them in this process instead"
+        )
     env = dict(os.environ)
     force = f"--xla_force_host_platform_device_count={int(n_devices)}"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + force).strip()

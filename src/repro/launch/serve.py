@@ -75,6 +75,7 @@ from repro.data.synthetic import (
     make_pipeline_median,
     poisson_arrivals,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving import BatchedFusedServer, BiathlonServer, ServingRuntime
 
 
@@ -148,6 +149,7 @@ def main():
                     "fused-continuous; incompatible with --devices)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     make = make_pipeline_median if args.median else make_pipeline
     bundle = make(

@@ -69,7 +69,6 @@ def _padded_vocab(v: int, multiple: int = 256) -> int:
 def _sharded_chunk_xent(rules, vp: int, vocab: int, n_chunks: int):
     """Returns shard_mapped fn(h, w, labels, mask) -> (loss_sum, correct)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = rules.mesh
     dp = rules.axis("batch")
@@ -116,7 +115,7 @@ def _sharded_chunk_xent(rules, vp: int, vocab: int, n_chunks: int):
         return loss_sum, correct
 
     b_axis = dp
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -126,7 +125,7 @@ def _sharded_chunk_xent(rules, vp: int, vocab: int, n_chunks: int):
             P(b_axis, None),
         ),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

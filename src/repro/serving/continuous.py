@@ -44,6 +44,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.analysis.contracts import assert_compile_contract
 from repro.core.executor_fused import (
@@ -55,6 +56,7 @@ from repro.core.executor_fused import (
 )
 from repro.core.pipeline import make_fused_model_fn
 from repro.data.store import bucket_size
+from repro.launch.mesh import LANES_AXIS
 from repro.serving.batched import (
     lane_request_inputs,
     sanitize_lane_inputs,
@@ -187,11 +189,8 @@ class ContinuousBatchedServer:
                 table, fresh,
             )
 
+        spec = PartitionSpec(LANES_AXIS)
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import PartitionSpec
-
-            spec = PartitionSpec("lanes")
             rows_per_dev = batch_size // self.n_devices
 
             def _refill_shard(table, vals, n, agg_ids, delta, exact, tau,
@@ -218,10 +217,10 @@ class ContinuousBatchedServer:
                 )
                 return _write_lane(table, safe, row)
 
-            refill_fn = shard_map(
+            refill_fn = jax.shard_map(
                 _refill_shard, mesh=mesh,
                 in_specs=(spec,) + (PartitionSpec(),) * 8,
-                out_specs=spec, check_rep=False,
+                out_specs=spec, check_vma=False,
             )
             self._chunk = shard_lanes_state_executor(_counted_chunk, mesh)
         elif cached:
@@ -245,7 +244,13 @@ class ContinuousBatchedServer:
             self._chunk = jax.jit(jax.vmap(_counted_chunk),
                                   donate_argnums=(0,))
 
-        self._refill = jax.jit(refill_fn, donate_argnums=(0,))
+        # a sharded table leaves every program as it entered: pinning the
+        # output sharding keeps XLA from reporting the zero-size leaves as
+        # replicated, which would make the next dispatch a new cache key
+        pinned = {} if mesh is None else {
+            "out_shardings": NamedSharding(mesh, spec)
+        }
+        self._refill = jax.jit(refill_fn, donate_argnums=(0,), **pinned)
         self._caps_seen: set[int] = set()
         max_n = max(
             bundle.store[f.table].group_size(g)
@@ -330,9 +335,17 @@ class ContinuousBatchedServer:
         else:
             lane = jax.eval_shape(self._init_fn, *dummy)
         lanes = self.batch_size
-        return jax.tree_util.tree_map(
+        table = jax.tree_util.tree_map(
             lambda s: jnp.zeros((lanes,) + s.shape, s.dtype), lane
         )
+        if self.mesh is not None:
+            # start sharded exactly as the refill/chunk programs return it:
+            # a table committed elsewhere is a different jit cache key, and
+            # the first dispatch after it would mint a second refill
+            table = jax.device_put(
+                table, NamedSharding(self.mesh, PartitionSpec(LANES_AXIS))
+            )
+        return table
 
     # ------------------------------------------------------------------
     def admit(self, table, cap: int, assignments):
