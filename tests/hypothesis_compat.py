@@ -17,7 +17,12 @@ import os
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import (  # noqa: F401
+        example,
+        given,
+        settings,
+        strategies as st,
+    )
 
     HAVE_HYPOTHESIS = True
 except ImportError:  # pragma: no cover - exercised only without the dev dep
@@ -41,6 +46,9 @@ except ImportError:  # pragma: no cover - exercised only without the dev dep
         return deco
 
     def settings(*_a, **_kw):
+        return lambda fn: fn
+
+    def example(*_a, **_kw):
         return lambda fn: fn
 
     class _AnyStrategy:
