@@ -53,6 +53,8 @@ from typing import Mapping
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import span
+
 __all__ = ["Table", "ColumnStore", "bucket_size", "build_table", "MAX_APPEND_LOG"]
 
 #: Append-log depth per group.  A cached entry older than this many
@@ -442,7 +444,8 @@ class ColumnStore:
             [min(self.tables[t].group_size(g), cap) for (t, c, g) in specs],
             np.int32,
         )
-        return jnp.asarray(bufs), jnp.asarray(sizes)
+        with span("put", h2d_bytes=bufs.nbytes + sizes.nbytes):
+            return jnp.asarray(bufs), jnp.asarray(sizes)
 
     def spec_versions(self, specs: list[tuple[str, str, int]]) -> tuple[int, ...]:
         """Per-spec group versions — the freshness half of a cache key."""

@@ -46,6 +46,7 @@ from repro.core.executor_fused import (
 from repro.core.pipeline import make_fused_model_fn
 from repro.data.store import bucket_size
 from repro.serving.feature_cache import FeatureCache
+from repro.tracing import span
 
 __all__ = [
     "BatchedFusedServer",
@@ -142,8 +143,10 @@ def lane_request_inputs(pipeline, store, req: dict, cap: int):
     """
     v, _ = store.request_buffers(pipeline.agg_specs(req), cap)
     true_n = np.asarray(pipeline.group_sizes(store, req), np.int64)
+    with span("fetch", d2h_bytes=v.nbytes):
+        vals = np.asarray(v, np.float32)
     return (
-        np.asarray(v, np.float32),
+        vals,
         np.minimum(true_n, cap).astype(np.int32),
         true_n,
         np.asarray(pipeline.exact_feature_values(store, req), np.float32),

@@ -63,6 +63,7 @@ from repro.serving.batched import (
     validate_serving_mesh,
 )
 from repro.serving.feature_cache import FeatureCache
+from repro.tracing import span
 
 __all__ = ["ContinuousBatchedServer"]
 
@@ -175,7 +176,9 @@ class ContinuousBatchedServer:
                 return self._init_fn(vals, n, agg_ids, delta, exact, active,
                                      tau, cap)
 
-        def _counted_chunk(state):
+        # the programs' names are the device trace's stable module names:
+        # jit_biathlon_refill and jit_biathlon_chunk
+        def biathlon_chunk(state):
             self._chunk_compiles += 1
             return chunk_fn(state)
 
@@ -193,8 +196,8 @@ class ContinuousBatchedServer:
         if mesh is not None:
             rows_per_dev = batch_size // self.n_devices
 
-            def _refill_shard(table, vals, n, agg_ids, delta, exact, tau,
-                              cap, lane):
+            def biathlon_refill(table, vals, n, agg_ids, delta, exact, tau,
+                                cap, lane):
                 # inside shard_map: `table` is this device's row block, the
                 # fresh-lane inputs are replicated.  Every device runs the
                 # (cheap, single-lane) init; only the owner of the global
@@ -218,30 +221,34 @@ class ContinuousBatchedServer:
                 return _write_lane(table, safe, row)
 
             refill_fn = jax.shard_map(
-                _refill_shard, mesh=mesh,
+                biathlon_refill, mesh=mesh,
                 in_specs=(spec,) + (PartitionSpec(),) * 8,
                 out_specs=spec, check_vma=False,
             )
-            self._chunk = shard_lanes_state_executor(_counted_chunk, mesh)
+            self._chunk = shard_lanes_state_executor(biathlon_chunk, mesh)
         elif cached:
 
-            def refill_fn(table, vals, n, agg_ids, delta, exact, tau, cap,
-                          lane, tables):
+            def biathlon_refill(table, vals, n, agg_ids, delta, exact, tau,
+                                cap, lane, tables):
                 fresh = _counted_init(vals, n, agg_ids, delta, exact,
                                       jnp.asarray(True), tau, cap, tables)
                 return _write_lane(table, fresh, lane)
 
-            self._chunk = jax.jit(jax.vmap(_counted_chunk),
+            refill_fn = biathlon_refill
+
+            self._chunk = jax.jit(jax.vmap(biathlon_chunk),
                                   donate_argnums=(0,))
         else:
 
-            def refill_fn(table, vals, n, agg_ids, delta, exact, tau, cap,
-                          lane):
+            def biathlon_refill(table, vals, n, agg_ids, delta, exact, tau,
+                                cap, lane):
                 fresh = _counted_init(vals, n, agg_ids, delta, exact,
                                       jnp.asarray(True), tau, cap)
                 return _write_lane(table, fresh, lane)
 
-            self._chunk = jax.jit(jax.vmap(_counted_chunk),
+            refill_fn = biathlon_refill
+
+            self._chunk = jax.jit(jax.vmap(biathlon_chunk),
                                   donate_argnums=(0,))
 
         # a sharded table leaves every program as it entered: pinning the
@@ -364,7 +371,6 @@ class ContinuousBatchedServer:
         clipping only shrinks the numerator).
         """
         p = self.bundle.pipeline
-        store = self.bundle.store
         cfg = self.config
         delta_default = (
             cfg.delta if cfg.delta is not None else p.delta_default
@@ -385,6 +391,20 @@ class ContinuousBatchedServer:
             seen.add(lane)
         self._caps_seen.add(cap)
         for lane, req, kn in assignments:
+            with span("refill", lane=lane):
+                table, true_rows[lane] = self._refill_lane(
+                    table, cap, lane, req, kn, delta_default
+                )
+        return table, true_rows
+
+    def _refill_lane(self, table, cap, lane, req, kn, delta_default):
+        """One lane of :meth:`admit`: gather its inputs on the host, put
+        them on the device, dispatch the refill.  Returns ``(table, the
+        request's true group rows)``."""
+        p = self.bundle.pipeline
+        store = self.bundle.store
+        cfg = self.config
+        with span("gather") as gather:
             if self.cache is not None:
                 # cached admission: vals/n/tables come device-resident from
                 # the LRU; the refill scatter copies them into the lane row,
@@ -411,13 +431,18 @@ class ContinuousBatchedServer:
                     vals, exact, policy=self.sanitize,
                     where=f"admit lane {lane}",
                 )
-            true_rows[lane] = int(true_n.sum())
-            delta = delta_default if kn is None else kn.delta
-            tau = cfg.tau if kn is None else kn.tau
-            iter_cap = (
-                cfg.max_iters if kn is None
-                else min(int(kn.iter_cap), cfg.max_iters)
-            )
+            gather.set_metadata(rows=int(np.minimum(true_n, cap).sum()))
+        delta = delta_default if kn is None else kn.delta
+        tau = cfg.tau if kn is None else kn.tau
+        iter_cap = (
+            cfg.max_iters if kn is None
+            else min(int(kn.iter_cap), cfg.max_iters)
+        )
+        # host arrays cross to the device; cached vals/n are there already.
+        # delta, tau, iter_cap and lane are 4-byte scalars
+        h2d = sum(a.nbytes for a in (vals, n, exact)
+                  if isinstance(a, np.ndarray)) + 4 * 4
+        with span("put", h2d_bytes=h2d):
             refill_args = (
                 table,
                 jnp.asarray(vals),
@@ -429,11 +454,11 @@ class ContinuousBatchedServer:
                 jnp.asarray(iter_cap, jnp.int32),
                 jnp.asarray(lane, jnp.int32),
             )
-            if self.cache is not None:
-                table = self._refill(*refill_args, entry.tables)
-            else:
-                table = self._refill(*refill_args)
-        return table, true_rows
+        if self.cache is not None:
+            table = self._refill(*refill_args, entry.tables)
+        else:
+            table = self._refill(*refill_args)
+        return table, int(true_n.sum())
 
     def run_chunk(self, table):
         """Advance every lane at most ``chunk_iters`` planner iterations."""
@@ -447,15 +472,12 @@ class ContinuousBatchedServer:
         Never touches ``vals``/``ptab``/``rindex`` — the big buffers stay
         device-resident across the whole table lifetime.
         """
-        return dict(
-            done=np.asarray(table.done),
-            active=np.asarray(table.active),
-            it=np.asarray(table.it, np.int64),
-            z=np.asarray(table.z),
-            n=np.asarray(table.n),
-            y_hat=np.asarray(table.y_hat),
-            prob=np.asarray(table.prob),
-        )
+        leaves = ("done", "active", "it", "z", "n", "y_hat", "prob")
+        nbytes = sum(getattr(table, name).nbytes for name in leaves)
+        with span("readback", d2h_bytes=nbytes):
+            out = {name: np.asarray(getattr(table, name)) for name in leaves}
+        out["it"] = out["it"].astype(np.int64)
+        return out
 
     # --- chunk-boundary checkpoint / rollback --------------------------
     @staticmethod
@@ -468,10 +490,12 @@ class ContinuousBatchedServer:
         unchanged), so this is the WHOLE state a rollback needs — a few KB
         per lane, no executables, no device work beyond the D2H copy.
         """
-        return {
-            name: np.asarray(getattr(table, name))
-            for name in CHUNK_CARRY_LEAVES
-        }
+        nbytes = sum(getattr(table, name).nbytes for name in CHUNK_CARRY_LEAVES)
+        with span("snapshot", d2h_bytes=nbytes):
+            return {
+                name: np.asarray(getattr(table, name))
+                for name in CHUNK_CARRY_LEAVES
+            }
 
     @staticmethod
     def restore(table, ckpt: dict[str, np.ndarray]):

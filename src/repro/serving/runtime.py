@@ -38,6 +38,10 @@ time is the real measured wall-clock of ``serve_batch`` — the runtime is a
 single-server queueing simulation whose service process is the actual
 compiled executor.  Backoff delays are virtual (added to the clock, never
 slept), so fault-recovery tests replay deterministically.
+:class:`ContinuousServingRuntime` keeps time on a :class:`RunClock`
+instead: the host clock, with idle jumps to the next arrival and backoff
+added, so every host step of a run — readback, snapshot, health screen,
+bookkeeping — is charged to the requests it delays.
 """
 from __future__ import annotations
 
@@ -56,12 +60,14 @@ from repro.serving.batched import (
 from repro.serving.continuous import ContinuousBatchedServer
 from repro.serving.degrade import DegradationController
 from repro.serving.faults import TransientExecutorError
+from repro.tracing import span
 
 __all__ = [
     "Arrival",
     "RequestRecord",
     "AdmissionBatcher",
     "RuntimeStats",
+    "RunClock",
     "ServingRuntime",
     "ContinuousServingRuntime",
 ]
@@ -176,7 +182,7 @@ class RuntimeStats:
 
     tau: float
     records: list[RequestRecord] = field(default_factory=list)
-    makespan_s: float = 0.0     # first arrival -> last completion (virtual)
+    makespan_s: float = 0.0     # first arrival -> end of the run (run clock)
     busy_s: float = 0.0         # total wall time spent inside serve_batch
     n_batches: int = 0
     compile_count: int = 0      # executables built DURING the run (post-warmup)
@@ -627,6 +633,31 @@ class ServingRuntime:
         return stats
 
 
+class RunClock:
+    """A run's time: the host clock since the run started, plus an offset.
+
+    The offset starts at the first arrival's time and grows by the time the
+    run skips: the idle jump to the next arrival (:meth:`jump_to`) and
+    retry backoff (:meth:`skip`), which is never slept, so fault replays
+    stay deterministic.  Everything else the host does between two reads
+    of :meth:`now` is charged.
+    """
+
+    def __init__(self, start: float = 0.0):
+        self._t0 = time.perf_counter()
+        self.offset = start
+
+    def now(self) -> float:
+        return time.perf_counter() - self._t0 + self.offset
+
+    def skip(self, dt: float) -> None:
+        self.offset += dt
+
+    def jump_to(self, t: float) -> None:
+        """Move the clock forward to ``t`` if it is behind it."""
+        self.offset += max(t - self.now(), 0.0)
+
+
 class ContinuousServingRuntime:
     """Chunk-granularity lane-table scheduler (continuous batching).
 
@@ -656,8 +687,20 @@ class ContinuousServingRuntime:
     The controller's ``observe`` feedback runs per chunk (service estimate
     = EWMA of chunk wall time).
 
-    Time model matches :class:`ServingRuntime`: virtual arrival clock,
-    measured wall-clock for every refill and chunk dispatch.
+    Time model: host clock, with idle jumps and backoff added
+    (:class:`RunClock`).  Arrivals keep their trace times; between two
+    reads of the clock everything the host did is charged — refill and
+    chunk dispatches, readbacks, snapshots, the health screen and the
+    bookkeeping — while ``busy_s`` sums the time inside ``admit`` and
+    ``run_chunk`` alone.
+
+    Tracing: ``run`` opens the spans ``biathlon.run``, ``.admission``,
+    ``.chunk`` and ``.screen`` (``repro.tracing``), and the server nests
+    ``.refill``, ``.gather``, ``.put``, ``.fetch``, ``.readback`` and
+    ``.snapshot`` inside them; a running ``jax.profiler`` records them
+    beside the device ops.  An admission span's ``admission`` counter is
+    its records' ``batch_id``, and a refill span's ``lane`` their
+    ``lane``.
 
     Fault tolerance (DESIGN.md § Fault tolerance): before every chunk
     dispatch the runtime snapshots the table's chunk-mutable carry
@@ -769,9 +812,12 @@ class ContinuousServingRuntime:
 
     # ------------------------------------------------------------------
     def run(self, arrivals, warmup: bool = True) -> RuntimeStats:
-        """Replay a timestamped arrival trace through the lane table."""
-        import jax
+        """Replay a timestamped arrival trace through the lane table.
 
+        Every time in the records reads one :class:`RunClock`, started
+        after warm-up at the first arrival's time: the host clock, with
+        idle jumps to the next arrival and retry backoff added.
+        """
         arr = sorted(
             (
                 a if isinstance(a, Arrival) else Arrival(float(a[0]), *a[1:])
@@ -779,6 +825,12 @@ class ContinuousServingRuntime:
             ),
             key=lambda a: a.t,
         )
+        with span("run", arrivals=len(arr)):
+            return self._replay(arr, warmup)
+
+    def _replay(self, arr: list[Arrival], warmup: bool) -> RuntimeStats:
+        import jax
+
         stats = RuntimeStats(
             tau=self.server.config.tau,
             n_devices=self.server.n_devices,
@@ -790,6 +842,7 @@ class ContinuousServingRuntime:
         if warmup:
             self.warmup([a.request for a in arr])
         compiles_before = self.server.compile_count
+        clock = RunClock(arr[0].t)
 
         deadlines = [
             a.t + a.slo_s
@@ -824,7 +877,6 @@ class ContinuousServingRuntime:
         iter_rows: list[np.ndarray] = []
         admissions = 0
         n_chunks = 0
-        now = arr[0].t
         i = 0
 
         def finalize(lane: int, out: dict, t_done: float) -> None:
@@ -892,11 +944,56 @@ class ContinuousServingRuntime:
             occupied[lane] = None
             knobs_by_lane[lane] = None
 
+        def admit(table, assignments):
+            """``server.admit`` and its wait on the table, retried on a
+            transient failure.  Returns ``(admitted, table, true_rows)``.
+
+            Admission is idempotent (the refill re-inits the whole lane
+            from counter-based RNG), so a transient failure just retries the
+            WHOLE admit — with every assignment's knobs re-priced against
+            its post-retry slack.
+            """
+            attempt = 0
+            while True:
+                t0 = clock.now()
+                try:
+                    table, tr = self.server.admit(table, cap, assignments)
+                    jax.block_until_ready(table)
+                except TransientExecutorError:
+                    stats.busy_s += clock.now() - t0
+                    if attempt >= self.max_retries:
+                        return False, table, {}
+                    clock.skip(self.backoff_s * (2.0**attempt))
+                    attempt += 1
+                    stats.n_retries += 1
+                    if ctl is not None:
+                        now = clock.now()
+                        assignments = [
+                            (
+                                lane,
+                                req,
+                                ctl.retier(
+                                    deadlines[occupied[lane]] - now
+                                    if math.isfinite(deadlines[occupied[lane]])
+                                    else None,
+                                    len(queue),
+                                    base_delta,
+                                ),
+                            )
+                            for lane, req, _kn in assignments
+                        ]
+                        for lane, _req, kn in assignments:
+                            knobs_by_lane[lane] = kn
+                    continue
+                stats.busy_s += clock.now() - t0
+                return True, table, tr
+
         while i < len(arr) or queue or any(l is not None for l in occupied):
             if not queue and all(l is None for l in occupied):
                 if i >= len(arr):
                     break
-                now = max(now, arr[i].t)  # idle: jump to the next arrival
+                clock.jump_to(arr[i].t)  # idle: jump to the next arrival
+            now = clock.now()
             while i < len(arr) and arr[i].t <= now:
                 queue.append(i)
                 i += 1
@@ -951,73 +1048,34 @@ class ContinuousServingRuntime:
                     stats.n_recycles += 1
                 lane_used[lane] = True
             if assignments:
-                admissions += 1
-                # admission is idempotent (the refill re-inits the whole
-                # lane from counter-based RNG), so a transient failure just
-                # retries the WHOLE admit — with every assignment's knobs
-                # re-priced against its post-retry slack
-                attempt = 0
-                admitted = True
-                while True:
-                    t0 = time.perf_counter()
-                    try:
-                        table, tr = self.server.admit(table, cap, assignments)
-                        jax.block_until_ready(table)
-                    except TransientExecutorError:
-                        dt = time.perf_counter() - t0
-                        now += dt
-                        stats.busy_s += dt
-                        if attempt >= self.max_retries:
-                            admitted = False
-                            break
-                        now += self.backoff_s * (2.0**attempt)
-                        attempt += 1
-                        stats.n_retries += 1
-                        if ctl is not None:
-                            assignments = [
-                                (
-                                    lane,
-                                    req,
-                                    ctl.retier(
-                                        deadlines[occupied[lane]] - now
-                                        if math.isfinite(
-                                            deadlines[occupied[lane]]
-                                        )
-                                        else None,
-                                        len(queue),
-                                        base_delta,
-                                    ),
-                                )
-                                for lane, req, _kn in assignments
-                            ]
-                            for lane, _req, kn in assignments:
-                                knobs_by_lane[lane] = kn
+                with span("admission", admission=admissions,
+                          lanes=len(assignments), queue=len(queue)):
+                    admitted, table, tr = admit(table, assignments)
+                    admissions += 1
+                    if not admitted:
+                        # retries exhausted before any lane was (fully)
+                        # refilled: the assigned requests fail; their lanes
+                        # are cleared in case a partial admit left them active
+                        dead = [lane for lane, _req, _kn in assignments]
+                        now = clock.now()
+                        for lane in dead:
+                            drop(lane, "failed", now)
+                            stats.n_failed += 1
+                        table = self.server.clear_lanes(table, dead)
                         continue
-                    dt = time.perf_counter() - t0
-                    now += dt
-                    stats.busy_s += dt
-                    break
-                if not admitted:
-                    # retries exhausted before any lane was (fully) refilled:
-                    # the assigned requests fail; their lanes are cleared in
-                    # case a partial admit left them active
-                    dead = [lane for lane, _req, _kn in assignments]
-                    for lane in dead:
-                        drop(lane, "failed", now)
-                        stats.n_failed += 1
-                    table = self.server.clear_lanes(table, dead)
-                    continue
-                fill = sum(l is not None for l in occupied)
-                for lane, rows in tr.items():
-                    true_rows[lane] = rows
-                    admit_fill[lane] = fill
-                # a fresh lane can be done straight from z⁰ (guarantee met
-                # at the initial plan) — recycle it before paying a chunk
-                out = self.server.readback(table)
-                for lane, _, _ in assignments:
-                    prev_z[lane] = np.asarray(out["z"][lane], np.int64)
-                    if out["done"][lane]:
-                        finalize(lane, out, now)
+                    fill = sum(l is not None for l in occupied)
+                    for lane, rows in tr.items():
+                        true_rows[lane] = rows
+                        admit_fill[lane] = fill
+                    # a fresh lane can be done straight from z⁰ (guarantee
+                    # met at the initial plan) — recycle it before paying a
+                    # chunk
+                    out = self.server.readback(table)
+                    now = clock.now()
+                    for lane, _, _ in assignments:
+                        prev_z[lane] = np.asarray(out["z"][lane], np.int64)
+                        if out["done"][lane]:
+                            finalize(lane, out, now)
             if all(l is None for l in occupied):
                 continue  # everything shed or instantly done; re-admit
             # ---- one chunk dispatch, checkpointed at the boundary: the
@@ -1026,90 +1084,67 @@ class ContinuousServingRuntime:
             # table back to this boundary and replays — counter-based RNG
             # makes the replay bitwise-identical, and both snapshot and
             # restore are host buffer swaps (zero new executables)
-            ckpt = self.server.snapshot(table)
-            attempt = 0
-            dispatched = True
-            while True:
-                t0 = time.perf_counter()
-                try:
-                    table = self.server.run_chunk(table)
-                    jax.block_until_ready(table)
-                except TransientExecutorError as e:
-                    dt = time.perf_counter() - t0
-                    now += dt
-                    stats.busy_s += dt
-                    # the raiser may hand back the wrecked table (e.g. a
-                    # mid-chunk crash leaving scrambled carry); adopt it so
-                    # the rollback is exercised against real damage, then
-                    # restore the last good boundary
-                    wreck = getattr(e, "table", None)
-                    if wreck is not None:
-                        table = wreck
-                    table = self.server.restore(table, ckpt)
-                    stats.n_rollbacks += 1
-                    if attempt >= self.max_retries:
-                        dispatched = False
-                        break
-                    now += self.backoff_s * (2.0**attempt)
-                    attempt += 1
-                    stats.n_retries += 1
-                    continue
-                dt = time.perf_counter() - t0
-                now += dt
-                stats.busy_s += dt
-                break
+            occ = np.array([l is not None for l in occupied])
+            with span("chunk", chunk=n_chunks, occupied=int(occ.sum())):
+                dispatched, table, dt = self._dispatch(table, stats, clock)
             if not dispatched:
                 # persistent dispatch failure: fail every resident request
                 # and clear their lanes so draining continues (bounded p99
                 # instead of an infinite retry loop)
                 dead = [l for l in range(lanes) if occupied[l] is not None]
+                now = clock.now()
                 for lane in dead:
                     drop(lane, "failed", now)
                     stats.n_failed += 1
                 table = self.server.clear_lanes(table, dead)
                 continue
             n_chunks += 1
-            out = self.server.readback(table)
-            occ = np.array([l is not None for l in occupied])
-            occ_rows.append(occ)
-            iter_rows.append(np.where(occ, out["it"] - prev_it, 0))
-            prev_it = out["it"].copy()
-            # ---- post-chunk numerical-health check: quarantine poisoned
-            # lanes (NaN/Inf carry, z regression, inconsistent done flag)
-            # without touching their healthy neighbors
-            poisoned: list[int] = []
-            for lane in range(lanes):
-                if occupied[lane] is None:
-                    continue
-                chunks_by_lane[lane] += 1
-                verdict = self._lane_health(
-                    out, lane, prev_z[lane], cap, knobs_by_lane[lane]
-                )
-                if verdict is None:
-                    prev_z[lane] = np.asarray(out["z"][lane], np.int64)
-                    if out["done"][lane]:
-                        finalize(lane, out, now)
-                    continue
-                poisoned.append(lane)
-                j = occupied[lane]
-                poison_attempts[j] = poison_attempts.get(j, 0) + 1
-                if poison_attempts[j] <= self.poison_retries:
-                    # bounded re-admission: the request goes back to the
-                    # FRONT of the queue and gets a full fresh admit (which
-                    # re-initializes every lane leaf), not a carry patch
-                    queue.appendleft(j)
-                    occupied[lane] = None
-                    knobs_by_lane[lane] = None
-                else:
-                    drop(lane, "poisoned", now)
-                    stats.n_poisoned += 1
-            if poisoned:
-                table = self.server.clear_lanes(table, poisoned)
-            if ctl is not None:
-                ctl.observe(dt, len(queue))
+            with span("screen", occupied=int(occ.sum())) as screen:
+                out = self.server.readback(table)
+                now = clock.now()
+                occ_rows.append(occ)
+                advanced = np.where(occ, out["it"] - prev_it, 0)
+                iter_rows.append(advanced)
+                prev_it = out["it"].copy()
+                # ---- post-chunk numerical-health check: quarantine
+                # poisoned lanes (NaN/Inf carry, z regression, inconsistent
+                # done flag) without touching their healthy neighbors
+                poisoned: list[int] = []
+                for lane in range(lanes):
+                    if occupied[lane] is None:
+                        continue
+                    chunks_by_lane[lane] += 1
+                    verdict = self._lane_health(
+                        out, lane, prev_z[lane], cap, knobs_by_lane[lane]
+                    )
+                    if verdict is None:
+                        prev_z[lane] = np.asarray(out["z"][lane], np.int64)
+                        if out["done"][lane]:
+                            finalize(lane, out, now)
+                        continue
+                    poisoned.append(lane)
+                    j = occupied[lane]
+                    poison_attempts[j] = poison_attempts.get(j, 0) + 1
+                    if poison_attempts[j] <= self.poison_retries:
+                        # bounded re-admission: the request goes back to the
+                        # FRONT of the queue and gets a full fresh admit
+                        # (which re-initializes every lane leaf), not a
+                        # carry patch
+                        queue.appendleft(j)
+                        occupied[lane] = None
+                        knobs_by_lane[lane] = None
+                    else:
+                        drop(lane, "poisoned", now)
+                        stats.n_poisoned += 1
+                if poisoned:
+                    table = self.server.clear_lanes(table, poisoned)
+                if ctl is not None:
+                    ctl.observe(dt, len(queue))
+                screen.set_metadata(lane_iters=int(advanced.sum()),
+                                    poisoned=len(poisoned))
 
         stats.records = [r for r in records if r is not None]
-        stats.makespan_s = now - arr[0].t
+        stats.makespan_s = clock.now() - arr[0].t
         stats.n_batches = admissions
         stats.n_chunks = n_chunks
         occ_m = (
@@ -1125,3 +1160,38 @@ class ContinuousServingRuntime:
         stats.compile_count = self.server.compile_count - compiles_before
         stats.compiled_buckets = self.server.compiled_buckets
         return stats
+
+    def _dispatch(self, table, stats, clock):
+        """Snapshot the carry, run one chunk and wait on it, rolling back
+        and replaying on a transient failure.  Returns ``(dispatched,
+        table, seconds of the last attempt)``."""
+        import jax
+
+        ckpt = self.server.snapshot(table)
+        attempt = 0
+        while True:
+            t0 = clock.now()
+            try:
+                table = self.server.run_chunk(table)
+                jax.block_until_ready(table)
+            except TransientExecutorError as e:
+                dt = clock.now() - t0
+                stats.busy_s += dt
+                # the raiser may hand back the wrecked table (e.g. a
+                # mid-chunk crash leaving scrambled carry); adopt it so the
+                # rollback is exercised against real damage, then restore
+                # the last good boundary
+                wreck = getattr(e, "table", None)
+                if wreck is not None:
+                    table = wreck
+                table = self.server.restore(table, ckpt)
+                stats.n_rollbacks += 1
+                if attempt >= self.max_retries:
+                    return False, table, dt
+                clock.skip(self.backoff_s * (2.0**attempt))
+                attempt += 1
+                stats.n_retries += 1
+                continue
+            dt = clock.now() - t0
+            stats.busy_s += dt
+            return True, table, dt
