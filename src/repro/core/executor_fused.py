@@ -112,6 +112,7 @@ from repro.kernels.sampled_agg.ops import (
     resolve_afc_plan,
 )
 from repro.kernels.sampled_agg.prefix_stats import (
+    N_POWERS,
     HolisticRankIndex,
     append_power_sums,
     build_rank_index,
@@ -144,7 +145,7 @@ class PrebuiltTables(NamedTuple):
 
     The handle the feature-store cache (serving/feature_cache.py) passes to
     a ``prebuilt=True`` executor instead of letting it run its internal
-    ``core.precompute``: ``ptab (k, cap, 4)`` prefix power-sum tables,
+    ``core.precompute``: ``ptab (k, 4, cap)`` prefix power-sum tables,
     ``shift (k,)`` their accumulation origin (= ``vals[:, 0]``), and the
     holistic :class:`HolisticRankIndex` (zero-size when the pipeline has no
     holistic features).  Built by :func:`build_afc_precompute`, which also
@@ -200,7 +201,7 @@ class LaneState(NamedTuple):
       ``done ()``        guarantee met / exhausted / capped — the lane is
                          recyclable
     incremental-AFC handles (PR 5)
-      ``ptab (k, cap, 4)``  prefix power-sum tables ((k, 0, 4) under rescan)
+      ``ptab (k, 4, cap)``  prefix power-sum tables ((k, 4, 0) under rescan)
       ``shift (k,)``        the tables' numerical shift
       ``rindex``            :class:`HolisticRankIndex` (zero-size leaves
                             when rescan or no holistic features)
@@ -1050,7 +1051,7 @@ def build_chunked_executor(
             active=act, tau=tau, iter_cap=iter_cap,
             z=z, it=it, y_hat=y_hat, prob=prob, idx=idx, reps=reps,
             done=~core.want_more(carry, act, tau, cap_eff, n),
-            ptab=ptab if ptab is not None else jnp.zeros((k, 0, 4), f32),
+            ptab=ptab if ptab is not None else jnp.zeros((k, N_POWERS, 0), f32),
             shift=shift if shift is not None else jnp.zeros((k,), f32),
             rindex=rindex if rindex is not None else empty_rank_index(),
         )

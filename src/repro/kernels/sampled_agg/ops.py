@@ -122,11 +122,11 @@ def prefix_power_sums(
     *,
     use_kernel: bool | None = None,
 ):
-    """(k, cap) -> (k, cap, 4) running prefix power sums of ``vals - shift``.
+    """(k, cap) -> (k, 4, cap) running prefix power sums of ``vals - shift``.
 
     The incremental-AFC precompute (one call per request, before the
-    while_loop); backend-routed exactly like :func:`moments`.  The table row
-    at ``z - 1`` is the ``[s1..s4]`` tail :func:`moments` would return at
+    while_loop); backend-routed exactly like :func:`moments`.  The table
+    column at ``z - 1`` is the ``[s1..s4]`` tail :func:`moments` would return at
     plan z (``prefix_stats.prefix_moments_at`` does the gather).
     """
     if _resolve_backend(use_kernel):

@@ -10,16 +10,18 @@ body touches O(1)-ish state per feature:
 
 * :func:`prefix_power_sums` — a tiled Pallas kernel (jnp oracle:
   :func:`prefix_power_sums_ref`) producing the inclusive running power sums
-  ``P_p[j, c] = Σ_{i ≤ c} (v_{j,i} − shift_j)^p`` for p = 1..4.  The AFC
-  (value, sigma) at ANY plan z is then one gather of the (k, 4) table row at
-  ``z − 1`` fed through the unchanged ``estimates_from_power_sums`` tail —
-  the per-iteration cost no longer depends on the group size.  Accumulation
-  is compensated (``compensated.py``): the cross-tile carry is a Kahan
-  (hi, lo) pair, the oracle an error-free-transform ``associative_scan`` —
-  f32 storage with double-precision-class accumulation, since a naive f32
-  running Σv⁴ visibly drifts by 60k-row heavy-tailed groups.
-  Memory: (k, cap, 4) f32 = 4× the values buffer, freed with it per request
-  (the values buffer itself is donated — serving/batched.py).
+  ``P[j, p, c] = Σ_{i ≤ c} (v_{j,i} − shift_j)^(p+1)`` for the powers 1..4.
+  The AFC (value, sigma) at ANY plan z is then one gather of the (k, 4)
+  column at ``z − 1`` fed through the unchanged ``estimates_from_power_sums``
+  tail — the per-iteration cost no longer depends on the group size.
+  Accumulation is compensated (``compensated.py``): the cross-column carry
+  is a Kahan (hi, lo) pair, the oracle an error-free-transform
+  ``associative_scan`` — f32 storage with double-precision-class
+  accumulation, since a naive f32 running Σv⁴ visibly drifts by 60k-row
+  heavy-tailed groups.  Layout: (k, 4, cap), cap on the lanes, so the 4
+  powers of a feature fill the sublanes of one (4, 128) tile densely.
+  Memory: 4× the values buffer, freed with it per request (the values
+  buffer itself is donated — serving/batched.py).
 
 * :func:`build_rank_index` / :func:`select_ranks_indexed` — the holistic
   (MEDIAN/QUANTILE) equivalent.  The column is argsorted ONCE with its
@@ -53,6 +55,7 @@ from repro.kernels.sampled_agg.compensated import comp_cumsum, kahan_step, two_s
 
 __all__ = [
     "N_POWERS",
+    "block_c",
     "prefix_power_sums",
     "prefix_power_sums_ref",
     "prefix_moments_at",
@@ -71,121 +74,162 @@ N_POWERS = 4  # [Σu, Σu², Σu³, Σu⁴] — count at z is just z
 # --------------------------------------------------------------------------
 # Parametric: running power-sum tables
 # --------------------------------------------------------------------------
-def _powers(v: jnp.ndarray) -> jnp.ndarray:
-    """(…, c) f32 -> (…, c, 4) stacked u, u², u³, u⁴."""
+LANES = 128                 # the TPU vector's lane width: one scan column
+_SCOPED_VMEM = 16 * 2**20   # the kernel's scoped-VMEM limit (TPU default)
+# columns scanned per loop step: their scans are independent, so unrolled
+# they overlap the Kahan carry that chains them (on a TPU v5e at k = 9 and
+# cap 131,072: 0.74 ms a call at 1, 0.39 at 2, 0.22 at 4, 0.41 at 8)
+COLUMNS_PER_STEP = 4
+
+
+def _power_list(v: jnp.ndarray) -> tuple[jnp.ndarray, ...]:
+    """u, u², u³, u⁴ of ``v``, each shaped like it."""
     v2 = v * v
-    return jnp.stack([v, v2, v2 * v, v2 * v2], axis=-1)
+    return v, v2, v2 * v, v2 * v2
 
 
+def _powers(v: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """The four powers of ``v`` stacked on a new ``axis``."""
+    return jnp.stack(_power_list(v), axis=axis)
+
+
+@jax.jit
 def prefix_power_sums_ref(
     vals: jnp.ndarray, shift: jnp.ndarray | None = None
 ) -> jnp.ndarray:
-    """(k, cap) f32 -> (k, cap, 4) inclusive prefix sums of (v−shift)^p.
+    """(k, cap) f32 -> (k, 4, cap) inclusive prefix sums of (v−shift)^p.
 
-    Compensated scan (O(ε·log n) error); the prefix row at index ``z − 1``
-    is exactly the ``[s1..s4]`` tail of ``sampled_moments``'s output at plan
+    Compensated scan (O(ε·log n) error); the column ``[:, :, z − 1]`` is
+    exactly the ``[s1..s4]`` tail of ``sampled_moments``'s output at plan
     z (count = z), so the two paths share ``estimates_from_power_sums``.
     """
     v = vals.astype(jnp.float32)
     if shift is not None:
         v = v - shift.astype(jnp.float32)[:, None]
-    return comp_cumsum(_powers(v), axis=1)
+    return comp_cumsum(_powers(v, axis=-2), axis=-1)
 
 
-def _prefix_kernel(shift_ref, vals_ref, out_ref, hi_ref, lo_ref, *, block_c: int):
-    ci = pl.program_id(1)
-    v = vals_ref[...].astype(jnp.float32) - shift_ref[...]
-    p = _powers(v)                               # (block_k, block_c, 4)
+def block_c(k: int, cap: int) -> int:
+    """Lanes of the table one grid step writes, from ``(k, cap)`` alone.
 
-    # within-tile inclusive scan: log-step doubling (Mosaic-safe static
-    # slices + concatenate; error O(ε·log block_c))
-    s = 1
-    while s < block_c:
-        p = p + jnp.concatenate(
-            [jnp.zeros_like(p[:, :s]), p[:, :-s]], axis=1
-        )
-        s *= 2
+    The largest power-of-two multiple of 128 whose double-buffered blocks
+    fit half the scoped VMEM: the (k, cap) input block, sublanes rounded up
+    to 8, and the (k, 4, cap) output block, its 4 powers counted as a full
+    8-sublane tile.  Never wider than ``cap`` rounded up to 128.
+    """
+    per_lane = 2 * 4 * (-(-k // 8) * 8 + 8 * k)
+    bc = LANES
+    while 2 * bc * per_lane <= _SCOPED_VMEM // 2 and bc < cap:
+        bc *= 2
+    return bc
 
-    @pl.when(ci == 0)
+
+def _prefix_kernel(shift_ref, vals_ref, out_ref, hi_ref, lo_ref):
+    k, bc = vals_ref.shape
+    step = min(COLUMNS_PER_STEP, bc // LANES)
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         hi_ref[...] = jnp.zeros_like(hi_ref)
         lo_ref[...] = jnp.zeros_like(lo_ref)
 
-    carry_hi = hi_ref[...]                        # (block_k, 4)
-    carry_lo = lo_ref[...]
-    # add the smaller correction first so it is not absorbed by the carry
-    out_ref[...] = carry_hi[:, None, :] + (p + carry_lo[:, None, :])
-    last = jax.lax.slice_in_dim(p, block_c - 1, block_c, axis=1)[:, 0, :]
-    hi, lo = kahan_step(carry_hi, carry_lo, last)
-    hi_ref[...] = hi
-    lo_ref[...] = lo
+    lane = jax.lax.broadcasted_iota(jnp.int32, (k, LANES), 1)
+    shift = shift_ref[...]                        # (k, 1)
+
+    def columns(g, carry):
+        his, los = carry                          # 4 × (k, 128) each
+        for q in range(step):
+            off = pl.multiple_of((g * step + q) * LANES, LANES)
+            v = vals_ref[:, pl.ds(off, LANES)].astype(jnp.float32) - shift
+            new_his, new_los = [], []
+            for p, u in enumerate(_power_list(v)):
+                # inclusive scan of the column: log-step doubling along lanes
+                s = 1
+                while s < LANES:
+                    u = u + jnp.where(lane >= s, pltpu.roll(u, s, 1), 0.0)
+                    s *= 2
+                # the smaller correction first, so the carry does not absorb it
+                out_ref[:, p, pl.ds(off, LANES)] = his[p] + (u + los[p])
+                last = jnp.broadcast_to(u[:, LANES - 1:], u.shape)
+                hi, lo = kahan_step(his[p], los[p], last)
+                new_his.append(hi)
+                new_los.append(lo)
+            his, los = tuple(new_his), tuple(new_los)
+        return his, los
+
+    carry = (
+        tuple(hi_ref[p] for p in range(N_POWERS)),
+        tuple(lo_ref[p] for p in range(N_POWERS)),
+    )
+    his, los = jax.lax.fori_loop(0, bc // (step * LANES), columns, carry)
+    for p in range(N_POWERS):
+        hi_ref[p] = his[p]
+        lo_ref[p] = los[p]
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "block_c", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def prefix_power_sums(
     vals: jnp.ndarray,                 # (k, cap) f32
     shift: jnp.ndarray | None = None,  # (k,) f32 accumulation origin
     *,
-    block_k: int = 8,
-    block_c: int = 256,
     interpret: bool,
 ) -> jnp.ndarray:
-    """Pallas twin of :func:`prefix_power_sums_ref`: (k, cap, 4) tables.
+    """Pallas twin of :func:`prefix_power_sums_ref`: (k, 4, cap) tables.
 
-    Grid (k_tiles, c_tiles) with c innermost; each feature row's running
-    totals live in a VMEM (hi, lo) Kahan pair across its column tiles, so
-    tile boundaries add no uncompensated rounding.  Shapes need not divide
-    the blocks — inputs are zero-padded and the output sliced back to
-    (k, cap).  The sliced-off padded region is NOT a valid prefix
-    continuation (zero-padded columns accumulate ``(0 - shift)^p``, not 0);
-    only the returned [:k, :cap] entries are meaningful.
+    Layout: cap is the lane (minor) axis and the 4 powers sit on sublanes,
+    the order the served lane table keeps, so the refill writes the
+    kernel's output into its lane slot with no slice and no relayout.
 
-    ``block_c`` is 256 because the TPU pads the output block's minor dim of
-    4 to 128 lanes in VMEM: at 1,024 the kernel needs ~21 MB of the 16 MiB
-    scoped VMEM.  ``shift`` enters as a ``(k, 1)`` column so its block obeys
-    the (8, 128) tiling rule.
+    Blocks: all k rows in one block, cap tiled by :func:`block_c` (8,192
+    lanes at the served widths).  A cap off the block grid is zero-padded
+    and the output sliced back; padded columns are not a valid prefix
+    continuation (they accumulate ``(0 − shift)^p``), but a prefix scan
+    never carries them backwards.
+
+    Scan: each 128-lane column is scanned by log-step doubling
+    (``pltpu.roll`` and a lane mask; plain f32 adds, error O(ε·log 128)),
+    and the columns are chained in order through a Kahan (hi, lo) carry
+    per feature row and power that lives in VMEM across the grid, so the
+    column and block boundaries add no uncompensated rounding.  Each loop
+    step scans ``COLUMNS_PER_STEP`` columns.  ``shift``
+    enters as a ``(k, 1)`` column so its block obeys the tiling rule.
     """
     k, cap = vals.shape
     if shift is None:
         shift = jnp.zeros((k,), jnp.float32)
-    block_k = min(block_k, k)
-    block_c = min(block_c, cap)
-    kp = -(-k // block_k) * block_k
-    capp = -(-cap // block_c) * block_c
-    if (kp, capp) != (k, cap):
-        vals = jnp.pad(vals, ((0, kp - k), (0, capp - cap)))
-        shift = jnp.pad(shift, (0, kp - k))
-    grid = (kp // block_k, capp // block_c)
+    bc = block_c(k, cap)
+    capp = -(-cap // bc) * bc
+    if capp != cap:
+        vals = jnp.pad(vals, ((0, 0), (0, capp - cap)))
     out = pl.pallas_call(
-        functools.partial(_prefix_kernel, block_c=block_c),
-        grid=grid,
+        _prefix_kernel,
+        grid=(capp // bc,),
         in_specs=[
-            pl.BlockSpec((block_k, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_k, block_c), lambda i, j: (i, j)),
+            pl.BlockSpec((k, 1), lambda j: (0, 0)),
+            pl.BlockSpec((k, bc), lambda j: (0, j)),
         ],
-        out_specs=pl.BlockSpec(
-            (block_k, block_c, N_POWERS), lambda i, j: (i, j, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((kp, capp, N_POWERS), jnp.float32),
+        out_specs=pl.BlockSpec((k, N_POWERS, bc), lambda j: (0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((k, N_POWERS, capp), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_k, N_POWERS), jnp.float32),
-            pltpu.VMEM((block_k, N_POWERS), jnp.float32),
+            pltpu.VMEM((N_POWERS, k, LANES), jnp.float32),
+            pltpu.VMEM((N_POWERS, k, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(shift.astype(jnp.float32)[:, None], vals)
-    return out[:k, :cap]
+    return out if capp == cap else out[..., :cap]
 
 
 def prefix_moments_at(ptab: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     """Gather the (k, 5) ``[count, s1..s4]`` moments row at plan z.
 
-    ``ptab``: (k, cap, 4) prefix tables; ``z``: (k,) int32 in [0, cap].
-    This is the whole per-iteration parametric AFC read — one gather,
-    independent of cap.  ``z == 0`` rows are all-zero (empty prefix).
+    ``ptab``: (k, 4, cap) prefix tables; ``z``: (k,) int32 in [0, cap].
+    This is the whole per-iteration parametric AFC read — one gather along
+    the lanes, independent of cap.  ``z == 0`` rows are all-zero (empty
+    prefix).
     """
-    cap = ptab.shape[1]
+    cap = ptab.shape[-1]
     idx = jnp.clip(z - 1, 0, cap - 1).astype(jnp.int32)
-    row = jnp.take_along_axis(ptab, idx[:, None, None], axis=1)[:, 0]
+    row = jnp.take_along_axis(ptab, idx[:, None, None], axis=-1)[..., 0]
     row = jnp.where(z[:, None] > 0, row, 0.0)
     return jnp.concatenate([z.astype(jnp.float32)[:, None], row], axis=1)
 
@@ -343,7 +387,7 @@ def select_ranks_indexed(
 # Streaming-append delta updates (DESIGN.md § Online feature store)
 # --------------------------------------------------------------------------
 def append_power_sums(
-    ptab: jnp.ndarray,       # (k, cap, 4) prefix power-sum tables
+    ptab: jnp.ndarray,       # (k, 4, cap) prefix power-sum tables
     shift: jnp.ndarray,      # (k,) the tables' accumulation origin
     j: jnp.ndarray,          # () int32 insertion position, 0 < j
     x: jnp.ndarray,          # (k,) inserted value per feature row
@@ -367,18 +411,18 @@ def append_power_sums(
     fires).  ``aff`` masks the update to the feature rows whose
     (table, group) the event belongs to.
     """
-    k, cap, _ = ptab.shape
-    pw = _powers(x.astype(jnp.float32) - shift.astype(jnp.float32))  # (k, 4)
+    k, _, cap = ptab.shape
+    pw = _powers(x.astype(jnp.float32) - shift.astype(jnp.float32), axis=-1)
     shifted = jnp.concatenate(
-        [jnp.zeros((k, 1, N_POWERS), jnp.float32), ptab[:, :-1]], axis=1
+        [jnp.zeros((k, N_POWERS, 1), jnp.float32), ptab[..., :-1]], axis=-1
     )
-    s, e = two_sum(shifted, pw[:, None, :])
+    s, e = two_sum(shifted, pw[..., None])
     upd = s + e
     c = jnp.arange(cap, dtype=jnp.int32)
     mask = (c[None, :] >= j) & (j < cap)
     if aff is not None:
         mask = mask & aff[:, None]
-    return jnp.where(mask[:, :, None], upd, ptab)
+    return jnp.where(mask[:, None, :], upd, ptab)
 
 
 def merge_sorted_prefix(

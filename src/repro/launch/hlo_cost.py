@@ -270,7 +270,7 @@ def _fusion_param_kinds(callee: _Comp):
         elif ins.op in ("dynamic-slice", "gather"):
             # Both address only the selected rows of their big operand:
             # charge the result bytes, not the whole table (a prefix-table
-            # gather reads k rows, not the (k, cap, 4) table it indexes).
+            # gather reads k rows, not the (k, 4, cap) table it indexes).
             srcs = _OPERANDS.findall(ins.line.split(ins.op + "(", 1)[1])
             if srcs:
                 src = srcs[0]
@@ -402,7 +402,7 @@ def _eval_comp(
         if op == "gather":
             # addressed traffic only: read the gathered rows + the index
             # operand, write the result — NOT the whole indexed table
-            # (billing it would claim a (k, cap, 4) prefix-table read per
+            # (billing it would claim a (k, 4, cap) prefix-table read per
             # O(1) AFC lookup).  The table is specifically operand 0 of
             # gather(operand, indices) — not "the biggest operand", which
             # would mischarge whenever the index tensor outgrows the table.

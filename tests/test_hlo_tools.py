@@ -152,7 +152,7 @@ ENTRY %main (tab: f32[3,4096,4], idx: s32[3,1]) -> f32[3,4] {
 
 def test_gather_charges_addressed_rows_not_the_table():
     """The incremental-AFC promise lives here: an O(1) prefix lookup must
-    bill the gathered rows + indices, NOT the (k, cap, 4) table it indexes
+    bill the gathered rows + indices, NOT the (k, 4, cap) table it indexes
     — otherwise every while body would look O(cap) and the flatness
     contract could never hold."""
     cost = analyze_hlo(_GATHER, 1)

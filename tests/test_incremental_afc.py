@@ -28,6 +28,7 @@ from repro.kernels.sampled_agg.ops import (
     prefix_power_sums as prefix_power_sums_dispatch,
 )
 from repro.kernels.sampled_agg.prefix_stats import (
+    block_c,
     build_rank_index,
     prefix_moments_at,
     prefix_power_sums,
@@ -72,15 +73,19 @@ def test_power_sums_compensated_at_60k():
     ]:
         rel = np.abs(np.asarray(got)[0] - want) / np.abs(want)
         assert rel.max() < 1e-6, (name, rel)
-    # prefix tables: every cumulative position, not just the total
+    # prefix tables: every cumulative position, not just the total, on
+    # battery_median's k = 10 rows of independent heavy-tailed groups (the
+    # kernel's 8 grid steps and every row's Kahan carry)
+    rows = np.stack([v] + [_heavy_tailed(seed=s) for s in range(1, 10)])
     f64 = np.stack(
-        [(v.astype(np.float64) ** p).cumsum() for p in range(1, 5)], axis=-1
+        [(rows.astype(np.float64) ** p).cumsum(axis=1) for p in range(1, 5)],
+        axis=1,
     )
     for name, tab in [
-        ("prefix_ref", prefix_power_sums_ref(vals)),
-        ("prefix_kernel", prefix_power_sums(vals, interpret=True)),
+        ("prefix_ref", prefix_power_sums_ref(jnp.asarray(rows))),
+        ("prefix_kernel", prefix_power_sums(jnp.asarray(rows), interpret=True)),
     ]:
-        rel = np.max(np.abs(np.asarray(tab)[0] - f64) / (np.abs(f64) + 1e-30))
+        rel = np.max(np.abs(np.asarray(tab) - f64) / (np.abs(f64) + 1e-30))
         assert rel < 1e-6, (name, rel)
     # the naive baseline really does lose the tail: strictly-sequential f32
     seq = np.float32(0.0)
@@ -124,23 +129,36 @@ def test_beta_order_stat_matches_beta_moments():
 
 
 # ------------------------------------------ prefix tables: kernel vs oracle
-@pytest.mark.parametrize("k,cap,block_k,block_c", [
-    (4, 512, 4, 128),
-    (5, 129, 8, 64),      # neither dim divides its block
-    (3, 1000, 2, 256),
-    (1, 64, 8, 1024),     # blocks larger than the data
+@pytest.mark.parametrize("k,cap", [
+    (4, 512),
+    (5, 129),             # cap off the 128-lane column grid
+    (3, 1000),
+    (1, 64),              # a block larger than the data
+    (9, 640),             # turbofan's k: no row pad
+    (10, 100),            # battery_median's k, a cap below 128
+    (9, 2 * 8192 + 300),  # three blocks, the last one ragged
 ])
-def test_prefix_power_sums_kernel_matches_ref(k, cap, block_k, block_c):
+def test_prefix_power_sums_kernel_matches_ref(k, cap):
     rng = np.random.default_rng(k * cap)
     vals = jnp.asarray(rng.normal(1.0, 3.0, (k, cap)).astype(np.float32))
     shift = vals[:, 0]
-    got = prefix_power_sums(
-        vals, shift, block_k=block_k, block_c=block_c, interpret=True
-    )
+    got = prefix_power_sums(vals, shift, interpret=True)
     want = prefix_power_sums_ref(vals, shift)
+    assert got.shape == want.shape == (k, 4, cap)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=3e-5, atol=1e-3
     )
+
+
+def test_prefix_block_rule_covers_the_served_widths():
+    """Blocks come from (k, cap) alone: the served widths take 8,192-lane
+    blocks (16 grid steps at cap 131,072, not 1,024), small caps one block
+    of their own width rounded up to a 128-lane column."""
+    for k in (9, 10):
+        assert block_c(k, 131072) == 8192
+    assert block_c(10, 100) == 128
+    assert block_c(3, 1000) == 1024
+    assert block_c(64, 131072) >= 128
 
 
 @pytest.mark.parametrize("z_list", [[0, 1, 7, 300], [300, 299, 2, 1]])

@@ -23,6 +23,7 @@ The persistent compile cache is off around these compiles, since an entry
 written for a described chip cannot be read back without one.
 """
 import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core.executor import BiathlonConfig
-from repro.data.synthetic import make_pipeline
+from repro.data.synthetic import make_pipeline, make_pipeline_median
 from repro.kernels.sampled_agg import ops
 from repro.kernels.sampled_agg.prefix_stats import prefix_power_sums
 from repro.kernels.sampled_agg.quantile_select import masked_select_ranks
@@ -171,8 +172,12 @@ def test_kernels_never_default_to_the_interpreter(fn):
 
 # ------------------------------------------- continuous server programs
 def _server(name, mesh=None):
-    b = make_pipeline(name, rows_per_group=200, n_train_groups=30,
-                      n_serve_groups=2, n_requests=2)
+    size = dict(rows_per_group=200, n_train_groups=30, n_serve_groups=2,
+                n_requests=2)
+    if name == "battery_median":
+        b = make_pipeline_median("battery", **size)
+    else:
+        b = make_pipeline(name, **size)
     return ContinuousBatchedServer(b, CFG, batch_size=LANES, chunk_iters=4,
                                    mesh=mesh)
 
@@ -225,6 +230,103 @@ def test_continuous_executables_compile(one_chip, kernel_routing, name, cap,
         "chunk": srv._chunk.lower(args[0]).compile().as_text(),
     }
     assert "tpu_custom_call" in text[kernel_in]
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$")
+_COMP = re.compile(r"^(ENTRY )?%([\w.\-]+) \(")
+
+
+def _computations(text):
+    """{computation: {instruction: (type, opcode, operands, called)}} of a
+    compiled module; the entry computation is keyed ``ENTRY``.  Operands
+    are instruction names, or the index of a ``parameter``."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = comps.setdefault("ENTRY" if m.group(1) else m.group(2), {})
+            continue
+        m = _INSTR.match(line)
+        if m and cur is not None:
+            name, typ, op, rest = m.groups()
+            args = rest.split(")", 1)[0]
+            operands = (
+                [int(args)] if op == "parameter"
+                else re.findall(r"%([\w.\-]+)", args)
+            )
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            cur[name] = (typ, op, operands, called and called.group(1))
+    return comps
+
+
+def _laid_out_bytes(typ):
+    """Bytes of an f32 array as laid out: its two minor dims rounded up to
+    the layout's tile."""
+    dims = [int(d) for d in re.search(r"\[([\d,]+)\]", typ).group(1).split(",")]
+    m = re.search(r"\{([\d,]+):T\((\d+),(\d+)\)", typ)
+    minor_to_major = [int(d) for d in m.group(1).split(",")]
+    tile = (int(m.group(3)), int(m.group(2)))     # minor dim first
+    n = 4
+    for i, d in enumerate(minor_to_major):
+        n *= -(-dims[d] // tile[i]) * tile[i] if i < 2 else dims[d]
+    return n
+
+
+def _in_any_memory_space(typ):
+    return re.sub(r"S\(\d+\)", "", typ)
+
+
+@pytest.mark.parametrize("name,k", [("turbofan", 9), ("battery_median", 10)])
+def test_refill_writes_the_prefix_table_once_lane_dense(one_chip,
+                                                         kernel_routing,
+                                                         name, k):
+    """The refill's prefix tables go from the kernel into the lane table
+    as they are: one ``prefix_power_sums`` kernel, its output within 2x of
+    the logical 4·k·cap f32 words, and nothing between it and the table's
+    ``dynamic-update-slice`` but bitcasts and the compiler's asynchronous
+    moves between memory spaces, which keep the shape and the tiling (no
+    slice, no pad, no relayout copy)."""
+    cap = 131072
+    srv = _server(name)
+    args = _refill_shapes(srv, cap, one_chip, one_chip)
+    comps = _computations(srv._refill.lower(*args).compile().as_text())
+    entry = comps["ENTRY"]
+
+    kernels = [n for n, (_, op, _, _) in entry.items()
+               if op == "custom-call" and n.startswith("prefix_power_sums")]
+    assert len(kernels) == 1, kernels
+    kernel_type = entry[kernels[0]][0]
+    assert _laid_out_bytes(kernel_type) <= 2 * 4 * k * cap * 4, kernel_type
+
+    table = f"f32[{LANES},{k},4,{cap}]"
+    writes = [n for n, (typ, op, _, _) in entry.items()
+              if typ.startswith(table)
+              and op in ("dynamic-update-slice", "fusion")]
+    assert len(writes) == 1, writes
+    _, op, operands, called = entry[writes[0]]
+    if op == "fusion":
+        # a fused write holds bitcasts around the update-slice, nothing else
+        fused = comps[called]
+        assert {v[1] for v in fused.values()} <= {
+            "parameter", "bitcast", "constant", "dynamic-update-slice"
+        }, fused
+        (dus,) = [v for v in fused.values() if v[1] == "dynamic-update-slice"]
+        src = dus[2][1]
+        while fused[src][1] == "bitcast":
+            src = fused[src][2][0]
+        assert fused[src][1] == "parameter", fused[src]
+        src = operands[fused[src][2][0]]
+    else:
+        src = operands[1]
+    while src != kernels[0]:
+        typ, op, operands, _ = entry[src]
+        assert op in ("bitcast", "copy-done", "copy-start"), (src, op)
+        if op == "copy-start":
+            # (destination, source, context): only the memory space moves
+            dest = typ.strip("()").split(", ")[0]
+            assert (_in_any_memory_space(dest)
+                    == _in_any_memory_space(entry[operands[0]][0])), typ
+        src = operands[0]
 
 
 def test_lanes_mesh_executables_compile(topo, kernel_routing):
