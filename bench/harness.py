@@ -91,12 +91,15 @@ def require_chips(chips: int):
 
 
 def check_features(cfg: dict, pipeline) -> None:
-    """The bundle serves the aggregates the configuration states."""
+    """The bundle serves the aggregates and the task the configuration states."""
     got = [[f.agg, f.column, f.quantile if f.agg == "quantile" else None]
            for f in pipeline.agg_features]
     want = [[f["op"], f["column"], f.get("q")] for f in cfg["features"]]
     if got != want or [e.name for e in pipeline.exact_features] != cfg["exact"]:
         raise ValueError(f"pipeline features {got} differ from the config {want}")
+    if pipeline.task != cfg["task"]:
+        raise ValueError(f"pipeline task {pipeline.task!r} differs from the "
+                         f"config's {cfg['task']!r}")
 
 
 def build(cfg: dict, deployment_seed: int, chips: int):
@@ -136,16 +139,10 @@ def build(cfg: dict, deployment_seed: int, chips: int):
     return bundle, server, [by_group[g] for g in sorted(by_group)]
 
 
-def model_of(cfg: dict, pipeline) -> reference.Trees:
-    """The served model's trees, as the reference reads them."""
-    model = pipeline.model
-    ens = model.ensemble
-    kinds = {"random_forest": True, "gradient_boosting": False}
-    return reference.Trees(
-        np.asarray(ens.feature), np.asarray(ens.threshold), np.asarray(ens.left),
-        np.asarray(ens.right), np.asarray(ens.value), ens.depth, model.base,
-        kinds[cfg["model"]["kind"]], pipeline.scaler_mean, pipeline.scaler_scale,
-    )
+def model_of(cfg: dict, pipeline, root=manifest.ROOT) -> reference.Model:
+    """The served model, read by the file of the configuration's kind."""
+    return reference.Model(manifest.model_module(cfg["model"]["kind"], root),
+                           pipeline, cfg["task"])
 
 
 def problem_of(cfg: dict, bundle, req: dict) -> dict:
@@ -165,7 +162,7 @@ def problem_of(cfg: dict, bundle, req: dict) -> dict:
     }
 
 
-def compare(cfg: dict, bundle, served, seed: int,
+def compare(cfg: dict, bundle, served, seed: int, model: reference.Model,
             precision=reference.F64) -> dict:
     """The compared numbers over a sample of served requests.
 
@@ -174,7 +171,6 @@ def compare(cfg: dict, bundle, served, seed: int,
     """
     planner = cfg["planner"]
     delta = bundle.pipeline.delta_default
-    trees = model_of(cfg, bundle.pipeline)
     rng = traffic.rng_for(seed, 2)
     pick = set(rng.choice(len(served), size=min(SAMPLE, len(served)),
                           replace=False).tolist()) if served else set()
@@ -187,16 +183,12 @@ def compare(cfg: dict, bundle, served, seed: int,
         req, rec = served[i]
         prob_in = problem_of(cfg, bundle, req)
         z, it = list(rec.z), int(rec.iters)
-        ref = reference.answer(prob_in, trees, z, it)
+        ref = reference.answer(prob_in, model, z, it)
         got = {"y_hat": rec.y_hat, "prob": rec.prob}
         if precision is not reference.F64:
-            ctl = reference.answer(prob_in, trees, z, it, precision)
-            got = {"y_hat": ctl.y_hat,
-                   "prob": reference.guarantee_prob(ctl.y_hat, ctl.y_ami, delta,
-                                                    precision)}
-        gaps = reference.request_gaps(
-            got, ref, delta,
-            lambda y, ref=ref: reference.guarantee_prob(y, ref.y_ami, delta))
+            ctl = reference.answer(prob_in, model, z, it, precision)
+            got = reference.served(ctl, model, delta, precision)
+        gaps = reference.gaps(got, ref, model, delta)
         worst["yhat_gap"] = max(worst["yhat_gap"], gaps["yhat_gap"])
         worst["prob_gap"] = max(worst["prob_gap"], gaps["prob_gap"])
         exhausted = all(zj >= nj for zj, nj in zip(z, prob_in["n"]))
@@ -227,10 +219,10 @@ def is_correct(served: list, checks: dict) -> bool:
     return bool(served) and all(v <= lim for v, lim in checks.values())
 
 
-def exact_share(cfg: dict, bundle, served) -> float:
+def exact_share(cfg: dict, bundle, served, model: reference.Model) -> float:
     """Share of served answers within δ of the exact pipeline answer (every
-    group aggregated whole); reported beside τ, decides nothing."""
-    trees = model_of(cfg, bundle.pipeline)
+    group aggregated whole), or of its class; reported beside τ, decides
+    nothing."""
     delta = bundle.pipeline.delta_default
     exact = {}
     hits = 0
@@ -241,8 +233,9 @@ def exact_share(cfg: dict, bundle, served) -> float:
             value, _, _ = reference.estimates(p["features"], p["groups"], p["n"],
                                               p["n"], 0, p["n_boot"])
             full = np.concatenate([value, np.asarray(p["exact"], np.float64)])
-            exact[key] = float(trees.predict(trees.scaled(full[None, :]))[0])
-        hits += abs(rec.y_hat - exact[key]) <= delta
+            exact[key] = model.label(model.raw(full[None, :])[0])
+        hits += (rec.y_hat == exact[key] if model.classifies
+                 else abs(rec.y_hat - exact[key]) <= delta)
     return hits / max(len(served), 1)
 
 
@@ -274,6 +267,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
     jax.monitoring.register_event_duration_secs_listener(clock)
 
     bundle, server, group_requests = build(cfg, cfg["size"]["deployment_seed"], chips)
+    model = model_of(cfg, bundle.pipeline, root)
     if wrap is not None:
         server = wrap(server)
     timed = spans.SpanServer(server, annotate=traced)
@@ -362,15 +356,15 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
         spans=list(timed.spans), latencies=list(timed.latencies), trace=reduced,
         peak=peak, cap=max(caps),
         shape={"k": p.k, "e": len(p.exact_features), "m": cfg["planner"]["m"],
-               "m_sobol": cfg["planner"]["m_sobol"],
-               "trees": p.model.ensemble.n_trees, "depth": p.model.ensemble.depth},
+               "m_sobol": cfg["planner"]["m_sobol"], "ops_per_row": model.ops_per_row()},
+        model=model,
     )
     # the program's state goes before the reference runs
     del runtime, timed, server
     result = finish(run, man, bundle, seed, devices, aborted, window_compiles,
                     memory_peak, traced, root)
     if control:
-        gaps = compare(cfg, bundle, served, seed, reference.Precision("bfloat16"))
+        gaps = compare(cfg, bundle, served, seed, model, reference.Precision("bfloat16"))
         ctl = checks_of(cfg, gaps, result["failed"] + (1 if aborted else 0),
                         window_compiles)
         checks = result.pop("checks")
@@ -390,8 +384,8 @@ def finish(run: Run, man: dict, bundle, seed: int, devices, aborted,
 
     cfg = run.config
     t0 = time.perf_counter()
-    gaps = compare(cfg, bundle, run.served, seed)
-    share = exact_share(cfg, bundle, run.served)
+    gaps = compare(cfg, bundle, run.served, seed, run.model)
+    share = exact_share(cfg, bundle, run.served, run.model)
     iters = [rec.iters for _, rec in run.served]
     say(f"[check] compared={gaps['compared']} reference_s="
         f"{time.perf_counter() - t0!r} within_delta_of_exact={share!r} "

@@ -1,10 +1,11 @@
 """``BENCHMARK.json``: load it, check it, and find each file by its name.
 
-A configuration, a traffic mix or a metric is added by adding its file and
-its entry; nothing here names one.  The checks are those a later entry can
-break: characters of names and units, every per-layer metric's ``moves``
-reported by each of its cells, every configuration used by a cell, and every
-file present.
+A configuration, a traffic mix, a metric or a model kind is added by adding
+its file and its entry; nothing here names one.  The checks are those a
+later entry can break: characters of names and units, every per-layer
+metric's ``moves`` reported by each of its cells, every configuration used
+by a cell, its task and the file of its model's kind, and every file
+present.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
 SOURCES_E2E = ("host_clock", "device_trace")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+TASKS = ("regression", "classification")
 
 
 class ManifestError(ValueError):
@@ -78,6 +80,7 @@ def validate(man: dict, root: pathlib.Path) -> None:
             _name(k, f"config {name} reduced key")
         if not (root / c["file"]).is_file():
             raise ManifestError(f"config {name}: no file {c['file']}")
+        check_config(json.loads((root / c["file"]).read_text()), name, root)
         if name in configs:
             raise ManifestError(f"config {name} appears twice")
         configs[name] = c
@@ -151,6 +154,26 @@ def validate(man: dict, root: pathlib.Path) -> None:
             )
 
 
+def check_config(cfg: dict, name: str, root: pathlib.Path) -> None:
+    """A configuration states its task, and its model's kind has a file.
+
+    A classification (δ = 0) judges the share of the m QMC rows of each
+    class, so its ``prob_gap`` limit lies below 1/m: one row of the wrong
+    class fails.
+    """
+    task = cfg.get("task")
+    if task not in TASKS:
+        raise ManifestError(f"config {name}: task {task!r} is not one of {TASKS}")
+    model_path(_name(cfg.get("model", {}).get("kind"), f"config {name} model kind"),
+               root)
+    if task == "classification":
+        limit = cfg["checks"]["prob_gap"]["limit"]
+        if not 0 <= limit < 1.0 / cfg["planner"]["m"]:
+            raise ManifestError(
+                f"config {name}: a classification's prob_gap limit {limit!r} "
+                f"must lie below 1/m = {1.0 / cfg['planner']['m']!r}")
+
+
 def cell(man: dict, name: str) -> dict:
     for w in man["workloads"]:
         if w["name"] == name:
@@ -182,13 +205,16 @@ def metric_path(name: str, root: pathlib.Path = ROOT) -> pathlib.Path:
     return path
 
 
-def reader_module(name: str, root: pathlib.Path = ROOT):
-    """The module of metric ``name``'s reader, loaded from its file."""
-    path = metric_path(name, root)
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _module(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def reader_module(name: str, root: pathlib.Path = ROOT):
+    """The module of metric ``name``'s reader, loaded from its file."""
+    return _module(metric_path(name, root), f"bench_metric_{name}")
 
 
 def reader(name: str, root: pathlib.Path = ROOT):
@@ -199,3 +225,16 @@ def reader(name: str, root: pathlib.Path = ROOT):
 def cell_metrics(man: dict, cell_name: str, group: str) -> list[dict]:
     """The metrics of ``group`` that ``cell_name`` reports."""
     return [m for m in man[group] if cell_name in metric_cells(m, man)]
+
+
+def model_path(kind: str, root: pathlib.Path = ROOT) -> pathlib.Path:
+    path = root / "bench" / "models" / f"{kind}.py"
+    if not path.is_file():
+        raise ManifestError(f"model kind {kind}: no file {path.relative_to(root)}")
+    return path
+
+
+def model_module(kind: str, root: pathlib.Path = ROOT):
+    """The module of model kind ``kind``, loaded from its file
+    (``bench/reference.py`` ``Model`` says what it gives)."""
+    return _module(model_path(kind, root), f"bench_model_{kind}")

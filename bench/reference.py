@@ -1,8 +1,10 @@
 """Plain reference of a served answer, and the comparison that decides ``correct``.
 
 For a request that the program served with plan ``z`` after ``it`` planner
-iterations, the reference recomputes, from the host store and the model's
-trees, in float64 NumPy:
+iterations, the reference recomputes, from the host store and the served
+model as the file of its kind reads it (``bench/models/<kind>.py``: the
+score of a row, its range where comparisons may go either way, the class
+boundary), in float64 NumPy:
 
 * the aggregate estimates over the first ``z_j`` rows of each group's
   stored sample order (paper §3.2: CLT with finite-population correction
@@ -10,8 +12,9 @@ trees, in float64 NumPy:
   order-statistic bootstrap replicates for MEDIAN/QUANTILE, appendix D);
 * the model on them: the point answer ŷ, and the m QMC rows of the AMI
   stage (paper §3.3, unscrambled Sobol points through Φ⁻¹);
-* the Eq. 1 guarantee probability Pr(|Y − ŷ| ≤ δ) of the Normal fitted to
-  the QMC outputs.
+* the Eq. 1 guarantee probability: for regression Pr(|Y − ŷ| ≤ δ) of the
+  Normal fitted to the QMC outputs; for classification (δ = 0) Pr(Y = ŷ),
+  the share of the QMC rows whose class is ŷ.
 
 It imports nothing of the program.  The bootstrap replicate ranks come from
 counter-based draws (key ``fold_in(PRNGKey(0), it)``, Marsaglia-Tsang gammas
@@ -31,38 +34,12 @@ import numpy as np
 from scipy.special import ndtr, ndtri
 from scipy.stats import qmc
 
-#: a tree comparison whose estimate lies within this share of the feature's
-#: own scale (|value| + scaler scale) of the threshold may go either way: the
-#: program's float32 estimates carry relative errors near 1e-6 (compensated
-#: prefix sums), so 1e-4 leaves them a hundredfold room, while bfloat16
-#: (2^-8 relative) lies forty times beyond it.
-AMBIGUITY = 1e-4
+from bench.precision import F64, Precision
+# the tree kinds' reading of an ensemble, kept under its old name here
+from bench.trees import AMBIGUITY, Trees  # noqa: F401
+
 #: the clip the AMI transform applies to its uniforms before Φ⁻¹
 U_CLIP = 1e-7
-
-
-class Precision:
-    """Rounding applied after every step: none (float64) or bfloat16."""
-
-    def __init__(self, name: str = "float64"):
-        self.name = name
-        if name == "float64":
-            self.dtype = None
-        elif name == "bfloat16":
-            import ml_dtypes
-
-            self.dtype = ml_dtypes.bfloat16
-        else:
-            raise ValueError(f"unknown precision {name!r}")
-
-    def __call__(self, a):
-        a = np.asarray(a, np.float64)
-        if self.dtype is None:
-            return a
-        return a.astype(self.dtype).astype(np.float64)
-
-
-F64 = Precision()
 
 
 # ---------------------------------------------------------------- estimates
@@ -181,69 +158,6 @@ def estimates(features, groups, z, n, it: int, n_boot: int, r: Precision = F64):
     return value, sigma, reps
 
 
-# ------------------------------------------------------------------- model
-class Trees:
-    """A tree ensemble as arrays: nodes split ``x[feature] <= threshold``,
-    leaves loop to themselves; ``mean`` averages the trees (random forest),
-    otherwise they add up (boosting, learning rate folded into the leaves)."""
-
-    def __init__(self, feature, threshold, left, right, value, depth: int,
-                 base: float, mean: bool, scaler_mean, scaler_scale):
-        self.feature = np.asarray(feature, np.int64)
-        self.threshold = np.asarray(threshold, np.float64)
-        self.left = np.asarray(left, np.int64)
-        self.right = np.asarray(right, np.int64)
-        self.value = np.asarray(value, np.float64)
-        self.depth = int(depth)
-        self.base = float(base)
-        self.mean = bool(mean)
-        self.mu = np.asarray(scaler_mean, np.float64)
-        self.scale = np.asarray(scaler_scale, np.float64)
-
-    @property
-    def n_trees(self) -> int:
-        return self.feature.shape[0]
-
-    def scaled(self, full, r: Precision = F64):
-        return r(r(full - self.mu) / self.scale)
-
-    def predict(self, xs, r: Precision = F64) -> np.ndarray:
-        """Outputs for scaled rows ``xs`` (rows, F)."""
-        thr, leaf = r(self.threshold), r(self.value)
-        t_idx = np.arange(self.n_trees)[:, None]
-        rows = np.arange(xs.shape[0])[None, :]
-        idx = np.zeros((self.n_trees, xs.shape[0]), np.int64)
-        for _ in range(self.depth):
-            f = self.feature[t_idx, idx]
-            go_left = xs[rows, f] <= thr[t_idx, idx]
-            idx = np.where(go_left, self.left[t_idx, idx], self.right[t_idx, idx])
-        total = r(np.sum(leaf[t_idx, idx], axis=0))
-        return r(self.base + (r(total / self.n_trees) if self.mean else total))
-
-    def interval(self, full) -> tuple[float, float]:
-        """(lowest, highest) output of one unscaled row when every comparison
-        within :data:`AMBIGUITY` of its threshold may go either way."""
-        xs = self.scaled(full)
-        eps = AMBIGUITY * (np.abs(full) + self.scale) / self.scale
-        lo = hi = 0.0
-        for t in range(self.n_trees):
-            nodes = {0}
-            for _ in range(self.depth):
-                nxt = set()
-                for i in nodes:
-                    f, th = self.feature[t, i], self.threshold[t, i]
-                    if abs(xs[f] - th) <= eps[f]:
-                        nxt.update((self.left[t, i], self.right[t, i]))
-                    else:
-                        nxt.add(self.left[t, i] if xs[f] <= th else self.right[t, i])
-                nodes = nxt
-            leaves = [self.value[t, i] for i in nodes]
-            lo, hi = lo + min(leaves), hi + max(leaves)
-        if self.mean:
-            lo, hi = lo / self.n_trees, hi / self.n_trees
-        return self.base + lo, self.base + hi
-
-
 # ------------------------------------------------------------ the guarantee
 _SOBOL: dict = {}
 
@@ -281,15 +195,69 @@ def guarantee_prob(y_hat: float, y, delta: float, r: Precision = F64) -> float:
     return float(r(ndtr((delta - bias) / sd) - ndtr((-delta - bias) / sd)))
 
 
+class Model:
+    """The served model, as the file of its kind reads it.
+
+    ``kind`` is the module of ``bench/models/<kind>.py``: ``of(pipeline)``
+    reads the model's arrays, ``raw(arrays, full, r)`` scores unscaled rows,
+    ``interval(arrays, full)`` gives each row's (lo, hi) score where
+    comparisons may go either way, ``threshold`` is the class boundary
+    (class 1 where the score is above it) and ``ops_per_row(arrays)`` the
+    operations one row needs.  ``task``: ``regression`` or
+    ``classification``.
+    """
+
+    def __init__(self, kind, pipeline, task: str):
+        self.kind, self.task = kind, task
+        self.arrays = kind.of(pipeline)
+
+    @property
+    def classifies(self) -> bool:
+        return self.task == "classification"
+
+    @property
+    def threshold(self) -> float:
+        return float(self.kind.threshold)
+
+    def label(self, score) -> float:
+        """What the program serves for a score: the score, or its class."""
+        return float(score > self.threshold) if self.classifies else float(score)
+
+    def raw(self, full, r: Precision = F64) -> np.ndarray:
+        return np.asarray(self.kind.raw(self.arrays, full, r), np.float64)
+
+    def interval(self, full) -> tuple[np.ndarray, np.ndarray]:
+        lo, hi = self.kind.interval(self.arrays, full)
+        return np.asarray(lo, np.float64), np.asarray(hi, np.float64)
+
+    def ops_per_row(self) -> int:
+        return int(self.kind.ops_per_row(self.arrays))
+
+    def classes(self, lo, hi) -> tuple[np.ndarray, np.ndarray]:
+        """(lowest, highest) class that scores in [lo, hi] allow.
+
+        The class decision ``score > threshold`` is one more comparison,
+        so a score within :data:`AMBIGUITY` of the boundary's own scale
+        (|threshold| + 1) may go either way, as a tree comparison may.
+        """
+        t = self.threshold
+        eps = AMBIGUITY * (abs(t) + 1.0)
+        return ((np.asarray(lo) > t + eps).astype(np.float64),
+                (np.asarray(hi) > t - eps).astype(np.float64))
+
+
 class Answer:
-    """What the reference (or a control in its precision) says of one request."""
+    """What the reference (or a control in its precision) says of one request:
+    the point's score and its range, the QMC rows' scores and, for
+    classification, their ranges."""
 
-    def __init__(self, y_hat, y_lo, y_hi, y_ami):
+    def __init__(self, y_hat, y_lo, y_hi, y_ami, ami_lo=None, ami_hi=None):
         self.y_hat, self.y_lo, self.y_hi, self.y_ami = y_hat, y_lo, y_hi, y_ami
+        self.ami_lo, self.ami_hi = ami_lo, ami_hi
 
 
-def answer(problem: dict, trees: Trees, z, it: int, r: Precision = F64) -> Answer:
-    """The model's answer at plan z.
+def answer(problem: dict, model: Model, z, it: int, r: Precision = F64) -> Answer:
+    """The model's scores at plan z.
 
     ``problem``: ``features`` [(op, q)], ``groups``, ``n``, ``exact`` (e,),
     ``m``, ``n_boot``.
@@ -300,13 +268,23 @@ def answer(problem: dict, trees: Trees, z, it: int, r: Precision = F64) -> Answe
     point = np.concatenate([value, exact])
     rows = ami_rows(value, sigma, reps, problem["m"], r)
     full = np.concatenate([rows, np.broadcast_to(exact, (rows.shape[0], exact.size))], 1)
-    y_ami = trees.predict(trees.scaled(full, r), r)
-    y_hat = float(trees.predict(trees.scaled(point[None, :], r), r)[0])
-    if r.dtype is None:
-        lo, hi = trees.interval(point)
-    else:
-        lo = hi = y_hat
-    return Answer(y_hat, lo, hi, y_ami)
+    y_ami = model.raw(full, r)
+    y_hat = float(model.raw(point[None, :], r)[0])
+    if r.dtype is not None:
+        return Answer(y_hat, y_hat, y_hat, y_ami, y_ami, y_ami)
+    lo, hi = model.interval(point[None, :])
+    ami = model.interval(full) if model.classifies else (None, None)
+    return Answer(y_hat, lo[0], hi[0], y_ami, *ami)
+
+
+def served(ans: Answer, model: Model, delta: float, r: Precision = F64) -> dict:
+    """What a program that computed ``ans`` would serve: ``y_hat`` and
+    ``prob``.  Classification: the point's class and the share of the QMC
+    rows in it, a count over m that no precision rounds."""
+    y = model.label(ans.y_hat)
+    if not model.classifies:
+        return {"y_hat": y, "prob": guarantee_prob(y, ans.y_ami, delta, r)}
+    return {"y_hat": y, "prob": float(np.mean((ans.y_ami > model.threshold) == y))}
 
 
 # ------------------------------------------------------------- comparison
@@ -355,3 +333,30 @@ def request_gaps(got: dict, ref: Answer, delta: float, m_prob) -> dict:
     p_ref = m_prob(min(max(y, ref.y_lo), ref.y_hi))
     return {"yhat_gap": gap / max(delta, 1e-12),
             "prob_gap": abs(float(got["prob"]) - p_ref), "prob_ref": p_ref}
+
+
+def class_gaps(got: dict, ref: Answer, model: Model) -> dict:
+    """The compared numbers of one request of a classification (δ = 0).
+
+    ``yhat_gap`` is 0 where the program's class is one the reference allows
+    at the point, else 1.  [p_lo, p_hi] is the share of the QMC rows whose
+    class is the program's ŷ, a row whose score range allows both classes
+    counting as either; ``prob_gap`` is the distance of the program's
+    ``prob`` from it, and ``prob_ref`` is p_hi.
+    """
+    y = float(got["y_hat"])
+    low, high = model.classes(ref.y_lo, ref.y_hi)
+    rows_low, rows_high = model.classes(ref.ami_lo, ref.ami_hi)
+    p_lo = float(np.mean((rows_low == y) & (rows_high == y)))
+    p_hi = float(np.mean((rows_low <= y) & (y <= rows_high)))
+    prob = float(got["prob"])
+    return {"yhat_gap": 0.0 if y in (float(low), float(high)) else 1.0,
+            "prob_gap": max(p_lo - prob, prob - p_hi, 0.0), "prob_ref": p_hi}
+
+
+def gaps(got: dict, ref: Answer, model: Model, delta: float) -> dict:
+    """The compared numbers of one request, as the configuration's task says."""
+    if model.classifies:
+        return class_gaps(got, ref, model)
+    return request_gaps(got, ref, delta,
+                        lambda y: guarantee_prob(y, ref.y_ami, delta))

@@ -3,10 +3,11 @@ from __future__ import annotations
 
 import copy
 import json
+import time
 
 import pytest
 
-from bench import manifest, traffic
+from bench import harness, manifest, traffic
 from bench.tests import tiny
 
 
@@ -94,3 +95,87 @@ def test_new_config_traffic_and_metric_need_only_files_and_entries(tmp_path):
     assert manifest.reader("served_per_round", root)(None) == 42.0
     assert manifest.config(man, "tiny", root)["size"]["rows_per_group"] == 1500
     assert manifest.traffic("tiny", root)["round_size"] == 6
+
+
+LINEAR = '''"""Linear regression: the scaled row times the coefficients, plus the
+intercept; no comparison, so a row's score range is the score."""
+import numpy as np
+
+from bench.precision import F64
+
+threshold = 0.0
+
+
+def of(pipeline):
+    model = pipeline.model
+    return (np.asarray(model.coef, np.float64), float(model.intercept),
+            np.asarray(pipeline.scaler_mean, np.float64),
+            np.asarray(pipeline.scaler_scale, np.float64))
+
+
+def raw(model, full, r):
+    coef, intercept, mu, scale = model
+    xs = r(r(np.asarray(full, np.float64) - mu) / scale)
+    return r(r(xs @ r(coef)) + intercept)
+
+
+def interval(model, full):
+    score = raw(model, full, F64)
+    return score, score
+
+
+def ops_per_row(model):
+    return 2 * model[0].size
+'''
+
+
+def tick_price(task: str = "regression") -> dict:
+    """Biathlon's Tick-Price (Table 1: linear regression over 1 AGG and 6
+    request features) as a configuration."""
+    cfg = json.loads((manifest.BENCH_DIR / "configs" / "turbofan.json").read_text())
+    cfg.update(name="tick_price", pipeline="tick_price", table="ticks", task=task,
+               model={"kind": "linear"}, features=[{"op": "avg", "column": "price"}],
+               exact=["bid", "ask", "spread", "vol", "hour", "lag_price"])
+    if task == "classification":
+        cfg["checks"] = {"yhat_gap": {"limit": 0}, "prob_gap": {"limit": 1e-3}}
+    return cfg
+
+
+def test_a_model_kind_joins_by_its_file_alone(tmp_path):
+    root = tiny.make_root(tmp_path, tick_price())
+    (root / "bench" / "models" / "linear.py").write_text(LINEAR)
+    man = manifest.load(root / "BENCHMARK.json")
+    res = harness.run_cell(tiny.CELL, 2**31 + 5, 1.0, False, t_start=time.perf_counter(),
+                           require_chip=False, man=man, root=root)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+
+
+def test_a_model_kind_without_its_file_is_refused(tmp_path):
+    cfg = tick_price()
+    cfg.update(name="bearing_imbalance", pipeline="bearing_imbalance", table="vibration",
+               task="classification", model={"kind": "mlp"}, exact=[],
+               checks={"yhat_gap": {"limit": 0}, "prob_gap": {"limit": 1e-3}})
+    root = tiny.make_root(tmp_path, cfg)
+    with pytest.raises(manifest.ManifestError, match="bench/models/mlp.py"):
+        manifest.load(root / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("change", [{"task": "ranking"},
+                                    {"checks": {"yhat_gap": {"limit": 0},
+                                                "prob_gap": {"limit": 1.0 / 64}}}])
+def test_a_config_without_a_sound_task_is_refused(tmp_path, change):
+    cfg = dict(tick_price("classification"), **change)
+    root = tiny.make_root(tmp_path, cfg)
+    (root / "bench" / "models" / "linear.py").write_text(LINEAR)
+    with pytest.raises(manifest.ManifestError, match="task|1/m"):
+        manifest.load(root / "BENCHMARK.json")
+
+
+def test_the_harness_refuses_a_task_that_differs_from_the_pipeline(tmp_path):
+    root = tiny.make_root(tmp_path, tick_price("classification"))
+    (root / "bench" / "models" / "linear.py").write_text(LINEAR)
+    man = manifest.load(root / "BENCHMARK.json")
+    cfg = manifest.config(man, "tiny", root)
+    with pytest.raises(ValueError, match="task"):
+        harness.build(cfg, cfg["size"]["deployment_seed"], 1)

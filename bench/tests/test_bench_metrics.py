@@ -57,7 +57,7 @@ def test_request_rows_match_the_paper_counts():
 
 
 def test_request_work_from_shapes():
-    shape = {"k": 5, "e": 1, "m": 1000, "m_sobol": 256, "trees": 60, "depth": 5}
+    shape = {"k": 5, "e": 1, "m": 1000, "m_sobol": 256, "ops_per_row": 60 * 5}
     ops, nbytes = work.request_work(shape, [3000] * 5, 2)
     rows = 1001 + 1792 + 2 * 2793
     assert ops == rows * 60 * 5
@@ -78,7 +78,7 @@ def test_peaks_are_keyed_by_device_kind():
 def test_step_mfu_counts_served_work_over_window_and_chips():
     class Rec:
         z, iters = (100, 100), 0
-    shape = {"k": 2, "e": 0, "m": 10, "m_sobol": 4, "trees": 2, "depth": 3}
+    shape = {"k": 2, "e": 0, "m": 10, "m_sobol": 4, "ops_per_row": 2 * 3}
     peak = {"flops": 1e3, "hbm_bytes_per_s": 1e12}
     run = _run(served=[(None, Rec())] * 4, shape=shape, peak=peak,
                window_s=2.0, chips=4)

@@ -1,11 +1,12 @@
 """A benchmark root at a size the CPU holds, beside the real one.
 
-It carries its own ``BENCHMARK.json``, a configuration, a traffic mix and
-the real metric readers, so building it also shows that a cell is added by
-files and entries alone.
+It carries its own ``BENCHMARK.json``, a configuration, a traffic mix, the
+real metric readers and model kinds, so building it also shows that a cell
+is added by files and entries alone.
 """
 from __future__ import annotations
 
+import copy
 import json
 import pathlib
 import shutil
@@ -15,18 +16,26 @@ from bench import manifest
 CELL = "tiny.backlog"
 
 
-def make_root(tmp: pathlib.Path, pipeline: str = "battery_median") -> pathlib.Path:
+def make_root(tmp: pathlib.Path, config: str | dict = "battery_median",
+              m: int = 64) -> pathlib.Path:
+    """A root whose one cell, ``tiny.backlog``, runs ``config`` (a
+    configuration of ``bench/configs`` by name, or one given whole) cut to
+    a size the CPU holds, with ``m`` QMC rows of the AMI stage."""
     tmp = pathlib.Path(tmp)
     bench = tmp / "bench"
     shutil.copytree(manifest.BENCH_DIR / "metrics", bench / "metrics")
+    shutil.copytree(manifest.BENCH_DIR / "models", bench / "models",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (bench / "configs").mkdir()
     (bench / "traffic").mkdir()
-    cfg = json.loads((manifest.BENCH_DIR / "configs" / f"{pipeline}.json").read_text())
+    if isinstance(config, str):
+        config = json.loads((manifest.BENCH_DIR / "configs" / f"{config}.json").read_text())
+    cfg = copy.deepcopy(config)
     # groups of 1,125-1,875 rows: cap 2,048, above the rescan crossover, so
     # the incremental path with its prefix tables and rank index serves them
     cfg["size"] = {"deployment_seed": 3, "rows_per_group": 1500,
                    "n_train_groups": 60, "n_serve_groups": 6, "request_log": 64}
-    cfg["planner"].update(m=64, m_sobol=16, n_bootstrap=32, max_iters=8)
+    cfg["planner"].update(m=m, m_sobol=16, n_bootstrap=32, max_iters=8)
     cfg["serving"] = {"lanes_per_chip": 2, "chunk_iters": 2}
     (bench / "configs" / "tiny.json").write_text(json.dumps(cfg))
     (bench / "traffic" / "tiny.json").write_text(json.dumps(

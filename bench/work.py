@@ -38,13 +38,13 @@ def request_work(shape: dict, z, iters: int) -> tuple[float, float]:
     """(operations, bytes) one served request required.
 
     Bytes: the rows it sampled (Σ_j z_j f32 values) and its model rows'
-    features ((k + e) f32 each).  Operations: one comparison per tree node
-    visited, trees × depth per model row.
+    features ((k + e) f32 each).  Operations: ``ops_per_row`` per model row,
+    as the file of the model's kind counts them (trees × depth for trees).
     """
     k, e = shape["k"], shape["e"]
     rows = request_rows(k, shape["m"], shape["m_sobol"], int(iters))
     nbytes = F32 * float(sum(int(x) for x in z)) + F32 * rows * (k + e)
-    ops = float(rows) * shape["trees"] * shape["depth"]
+    ops = float(rows) * shape["ops_per_row"]
     return ops, nbytes
 
 
